@@ -118,33 +118,22 @@ def env_meta() -> dict:
     """Environment provenance for every ``BENCH_*.json``: numbers are
     meaningless without knowing what produced them.  Records python /
     jax / jaxlib versions, the backend platform and device kinds, and
-    the registered target names; degrades gracefully (``jax: null``)
-    when jax is absent."""
-    meta = {
+    the registered target names."""
+    import jax
+    import jaxlib
+
+    from repro.core.backends import default_registry
+    return {
         "python": platform.python_version(),
         "platform": platform.platform(),
         "machine": platform.machine(),
-        "jax": None, "jaxlib": None, "backend": None, "devices": [],
-        "targets": [],
+        "jax": jax.__version__,
+        "jaxlib": jaxlib.__version__,
+        "backend": jax.default_backend(),
+        "devices": [{"id": d.id, "platform": d.platform,
+                     "kind": d.device_kind} for d in jax.devices()],
+        "targets": default_registry().names(),
     }
-    try:
-        import jax
-        import jaxlib
-        meta["jax"] = jax.__version__
-        meta["jaxlib"] = jaxlib.__version__
-        meta["backend"] = jax.default_backend()
-        meta["devices"] = [
-            {"id": d.id, "platform": d.platform,
-             "kind": getattr(d, "device_kind", "?")}
-            for d in jax.devices()]
-    except Exception as e:  # pragma: no cover - jax-less env
-        meta["jax_error"] = f"{type(e).__name__}: {e}"
-    try:
-        from repro.core.backends import default_registry
-        meta["targets"] = default_registry().names()
-    except Exception as e:  # pragma: no cover
-        meta["targets_error"] = f"{type(e).__name__}: {e}"
-    return meta
 
 
 class Timer:

@@ -68,6 +68,7 @@ import threading
 import time
 from typing import Any, Callable, Mapping, Sequence
 
+import jax
 import numpy as np
 
 from repro.fault.manager import RecoverableError
@@ -75,13 +76,9 @@ from repro.fault.manager import RecoverableError
 from .errors import ExecutionError, PULostError
 from .faults import (_JOIN_GRACE, ExecutionPolicy, FaultPlan, RunContext,
                      _Aborted, run_with_retries)
+from .hoist import jit_hoisting_constants
 from .op import OpGraph
 from .targets import variant_tolerance
-
-try:  # the compiled path degrades to composed-Python without jax
-    import jax
-except Exception:  # pragma: no cover - jax is baked into this container
-    jax = None
 
 # segment execution modes
 COLD = "cold"        # not yet run: next run probes eagerly, then compiles
@@ -135,6 +132,26 @@ def _within_tolerance(ref, got, target) -> bool:
                             atol=atol, rtol=rtol))
 
 
+def _tolerance_miss(ref_outs, got, target) -> str:
+    """Why ``got`` fails :func:`_within_tolerance` against ``ref_outs``:
+    the first output outside the bucket, with its largest error."""
+    if len(got) != len(ref_outs):
+        return f"{len(got)} outputs, expected {len(ref_outs)}"
+    for t, (ref, out) in enumerate(zip(ref_outs, got)):
+        if _within_tolerance(ref, out, target):
+            continue
+        a, b = np.asarray(ref), np.asarray(out)
+        if a.shape != b.shape or a.dtype != b.dtype:
+            return (f"output {t} is {b.dtype}{list(b.shape)}, expected "
+                    f"{a.dtype}{list(a.shape)}")
+        err = np.abs(a.astype(np.float64) - b.astype(np.float64))
+        atol, rtol = (target.tolerance(a.dtype) if target is not None
+                      else variant_tolerance(a.dtype))
+        return (f"output {t} max_abs_err={err.max():.3e} beyond "
+                f"(atol={atol:g}, rtol={rtol:g})")
+    return "outputs differ"
+
+
 @dataclasses.dataclass
 class Segment:
     """A maximal run of same-lane ops fused into one callable.
@@ -150,9 +167,13 @@ class Segment:
     ``var_fns`` the target-dialect variants; the cold run verifies the
     variant composition against the reference outputs (bitwise, else the
     target's per-dtype tolerance) before it is ever served, and the
-    target's ``jit``/``device`` policy governs compilation and input
-    placement.  ``verified`` records the outcome (``"bitwise"`` /
-    ``"tolerance"`` / ``"rejected"`` / ``"error: ..."``).
+    target's ``jit``/``device``/``interpret`` policy governs compilation,
+    input placement and how its Pallas kernels run.  ``verified`` records
+    the outcome (``"bitwise"`` / ``"tolerance"`` / ``"rejected"`` /
+    ``"rejected: <first output outside the bucket>"`` / ``"error: <type>:
+    <message>"``); ``jit_verified`` records which rule admitted the jit,
+    or why it was refused (``"rejected: ..."`` / ``"error: ..."``, the
+    segment then runs composed-Python).
     """
 
     index: int
@@ -212,7 +233,7 @@ class Segment:
         """Pin segment inputs to the bound target's device (identity when
         no target/device is bound)."""
         tgt = self.target
-        if tgt is None or tgt.device is None or jax is None:
+        if tgt is None or tgt.device is None:
             return flat, ext_lists
         def put(v):
             return jax.device_put(v, tgt.device)
@@ -230,10 +251,13 @@ class Segment:
             outs = self._jfn(flat, ext_lists)
         elif self.mode == PYTHON and self.use_variant:
             outs = self._composed_var(*self._place(flat, ext_lists))
+        elif self.mode == COLD:
+            # the reference probe runs where the lane runs
+            flat, ext_lists = self._place(flat, ext_lists)
+            outs = self._composed(flat, ext_lists)
+            self._settle(flat, ext_lists, outs)
         else:
             outs = self._composed(flat, ext_lists)
-            if self.mode == COLD:
-                self._settle(flat, ext_lists, outs)
         for (r, i), o in zip(self.items, outs):
             results[r][i] = o
 
@@ -266,7 +290,7 @@ class Segment:
             pflat, pext = self._place(flat, ext_lists)
             got = self._composed_var(pflat, pext)
         except Exception as e:
-            self.verified = f"error: {type(e).__name__}"
+            self.verified = f"error: {type(e).__name__}: {e}"
             self.var_fns = None
             return None
         if len(got) == len(ref_outs) and all(
@@ -277,7 +301,8 @@ class Segment:
                 for a, b in zip(ref_outs, got)):
             self.verified = "tolerance"
         else:
-            self.verified = "rejected"
+            self.verified = ("rejected: "
+                             + _tolerance_miss(ref_outs, got, self.target))
             self.var_fns = None
             return None
         self.use_variant = True
@@ -308,15 +333,16 @@ class Segment:
         softmax composition is rarely bitwise; a declared-tolerance
         target says so in data rather than silently eating the ~100x
         eager fallback.  Targetless segments (the PR 5 analytic path)
-        remain strictly bitwise.  On success ``_jfn`` wraps the jitted
-        callable with the target's device placement and ``mode`` flips
-        to JIT; ``jit_verified`` records which rule admitted it."""
-        if jax is None:
-            return
+        remain strictly bitwise.  The closed-over weights are arguments of the jitted program, not constants in it
+        (:func:`~repro.core.hoist.jit_hoisting_constants`), placed on the
+        target's device.  On success ``_jfn`` wraps the jitted callable
+        with the target's device placement and ``mode`` flips to JIT;
+        ``jit_verified`` records which rule admitted it."""
         if not all(isinstance(o, jax.Array) for o in outs):
             return
         tgt = self.target
         declared = tgt is not None and (tgt.atol or tgt.rtol)
+        miss = []
 
         def admit(ref_o, got_o):
             if len(got_o) != len(ref_o):
@@ -326,10 +352,13 @@ class Segment:
             if declared and all(_within_tolerance(a, b, tgt)
                                 for a, b in zip(ref_o, got_o)):
                 return "tolerance"
+            miss.append(_tolerance_miss(ref_o, got_o, tgt) if declared
+                        else "not bitwise, and no tolerance is declared")
             return None
 
         try:
-            jfn = jax.jit(composed)
+            jfn = jit_hoisting_constants(
+                composed, tgt.device if tgt is not None else None)
             how = admit(outs, tuple(jfn(flat, ext_lists)))
             if how is not None:
                 flat2 = tuple(_perturb(v) for v in flat)
@@ -340,9 +369,12 @@ class Segment:
                 how = (None if how2 is None
                        else ("bitwise" if how == how2 == "bitwise"
                              else "tolerance"))
-        except Exception:
+        except Exception as e:
+            self.jit_verified = f"error: {type(e).__name__}: {e}"
             return
-        if how is not None:
+        if how is None:
+            self.jit_verified = "rejected: " + (miss[-1] if miss else "")
+        else:
             if self.target is not None and self.target.device is not None:
                 self._jfn = lambda f, e: tuple(jfn(*self._place(f, e)))
             else:
@@ -736,7 +768,7 @@ def compile_lane_program(graphs: Sequence[OpGraph],
         vf = [graphs[r].ops[i].payload_for(tgt.dialect)
               for (r, i) in seg.items]
         if any(v is not f for v, f in zip(vf, seg.fns)):
-            seg.var_fns = vf
+            seg.var_fns = [tgt.bind(v) for v in vf]
 
     for seg in segments:
         internal = {it: t for t, it in enumerate(seg.items)}

@@ -29,6 +29,7 @@ import numpy as np
 _log = logging.getLogger(__name__)
 
 from .costmodel import CostEntry, CostTable, EdgeSoCCostModel, PUSpec
+from .hoist import jit_hoisting_constants
 from .op import FusedOp, OpGraph
 
 # jaxpr primitive -> op kind classification
@@ -179,12 +180,12 @@ def measure_callable_stats(fn: Callable, args: Sequence[Any], *,
     dispatch cost alone).  ``jit=False`` measures the payload eagerly
     (still fenced — eager JAX is async too), which is what non-jitting
     targets (NumPy/eager backends) execute; ``device`` pins the inputs
-    with ``jax.device_put`` first so transfers are not billed to the
-    kernel.
+    (and, jitted, the arrays ``fn`` closes over) with ``jax.device_put``
+    first so transfers are not billed to the kernel.
     """
     if device is not None:
         args = tuple(jax.device_put(a, device) for a in args)
-    run = jax.jit(fn) if jit else fn
+    run = jit_hoisting_constants(fn, device) if jit else fn
     for _ in range(max(warmup, 1)):   # at least once: trigger compilation
         jax.block_until_ready(run(*args))
     ts = []
@@ -324,7 +325,7 @@ class MeasuredProfiler:
             for lane, tgt in targets.items():
                 if lane in unsupported or tgt.name in unsupported:
                     continue
-                fn = op.payload_for(tgt.dialect)
+                fn = tgt.bind(op.payload_for(tgt.dialect))
                 if fn is None or "example_inputs" not in op.meta:
                     est = self._analytic_anchor(op)
                     if est is None:

@@ -68,7 +68,9 @@ class Target:
     returns ``op.variants[dialect]`` when present, else the reference
     ``op.fn``.  ``jit=False`` targets (eager/NumPy backends) are never
     ``jax.jit``-ed by the compiled path or the profiler.  ``device``
-    pins segment inputs via ``jax.device_put`` before execution.
+    pins segment inputs via ``jax.device_put`` before execution, and
+    ``interpret`` (when not ``None``) forces the Pallas kernels of the
+    served payloads interpreted or compiled (:meth:`bind`).
 
     The pricing fields feed :meth:`pu_spec`: ``handoff_s`` becomes the
     cost-table H2D/D2H column (charged by ``transition_cost`` on lane
@@ -83,7 +85,7 @@ class Target:
     dialect: str = "ref"           # variant-table key; "ref" = op.fn oracle
     jit: bool = True               # jit fused segments / profile jitted
     device: Any = None             # a jax.Device, or None = wherever-is
-    interpret: bool | None = None  # pallas interpret-mode knob (data only)
+    interpret: bool | None = None  # Pallas interpret mode; None = backend
     is_accelerator: bool = False   # gate handoff pricing + boundary H2D/D2H
     dispatch_s: float = 2e-5       # per-op dispatch charged in the table
     handoff_s: float = 2.5e-4      # priced cross-lane sync (h2d = d2h)
@@ -98,6 +100,19 @@ class Target:
         at, rt = variant_tolerance(dtype)
         return (self.atol if self.atol is not None else at,
                 self.rtol if self.rtol is not None else rt)
+
+    def bind(self, fn):
+        """``fn`` as this target runs it: under the target's Pallas
+        interpret setting when it declares one (identity otherwise)."""
+        if fn is None or self.interpret is None:
+            return fn
+        from repro.kernels.ops import interpret_mode
+        flag = self.interpret
+
+        def run(*args):
+            with interpret_mode(flag):
+                return fn(*args)
+        return run
 
     def pu_spec(self) -> PUSpec:
         """Synthesize the planner-side PUSpec for this target.

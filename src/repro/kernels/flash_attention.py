@@ -4,9 +4,10 @@ TPU mapping of the attention hot-spot (the paper's GEMM-affinity operator
 class): online-softmax over MXU-aligned (block_q x block_k) score tiles,
 fp32 accumulators in VMEM scratch, q/k/v streamed HBM->VMEM by BlockSpec.
 
-Grid: (B, Hq, num_q_blocks, num_kv_blocks).  The kv axis is the innermost,
-sequential ("arbitrary") dimension; acc/m/l scratch carries across it.  GQA
-is handled in the k/v index maps (query head h reads kv head h // group).
+Grid: (B, Hq, num_q_blocks, num_kv_blocks) over head-major (B, H, T, D)
+arrays.  The kv axis is the innermost, sequential ("arbitrary")
+dimension; acc/m/l scratch carries across it.  GQA is handled in the k/v
+index maps (query head h reads kv head h // group).
 Causal skipping: kv blocks strictly above the diagonal are not processed
 (@pl.when), which halves compute for causal masks.
 
@@ -45,11 +46,15 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         jnp.int32, (block_q, block_k), 1)
 
     def _process():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale       # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)               # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)               # (bk, Dv)
+        # operands stay in the input dtype (bf16 feeds the MXU natively);
+        # f32 inputs contract at full f32 precision.  Accumulation is f32.
+        q = q_ref[0, 0]                                          # (bq, D)
+        k = k_ref[0, 0]                                          # (bk, D)
+        v = v_ref[0, 0]                                          # (bk, Dv)
+        prec = _precision(q.dtype)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+                                precision=prec,
+                                preferred_element_type=jnp.float32) * scale
         mask = k_pos < kv_len                                    # kv padding
         if causal:
             mask = jnp.logical_and(mask, q_pos >= k_pos)
@@ -62,7 +67,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         l_ref[...] = l_prev * alpha + p.sum(axis=-1, keepdims=True)
         m_ref[...] = m_new
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), precision=prec,
             preferred_element_type=jnp.float32)
 
     if causal:
@@ -79,7 +84,14 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     @pl.when(ki == num_kv - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+
+
+def _precision(dtype):
+    """Full f32 contraction for f32 operands (the MXU's default pass
+    count would round them to bf16); the native pass otherwise."""
+    return (jax.lax.Precision.HIGHEST if dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
 
 
 @functools.partial(
@@ -92,6 +104,9 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
 
     Returns (B,Tq,Hq,Dv) in q.dtype.  Tq/Tk are padded to the block sizes
     internally; padded kv positions are masked, padded q rows dropped.
+    The kernel runs head-major, ``(B, H, T, D)``: a ``(block, D)`` tile is
+    then the whole trailing pair of a block, the layout the TPU's
+    (8, 128) tiling accepts at any head width.
     """
     B, Tq, Hq, D = q.shape
     _, Tk, Hk, _ = k.shape
@@ -105,11 +120,9 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
     nq = -(-Tq // block_q)
     nk = -(-Tk // block_k)
     pq, pk = nq * block_q - Tq, nk * block_k - Tk
-    if pq:
-        q = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0)))
-    if pk:
-        k = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
+    q = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    k = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
 
     kernel = functools.partial(
         _attn_kernel, scale=scale, causal=causal, q_offset=q_offset,
@@ -119,15 +132,15 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
         kernel,
         grid=(B, Hq, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D),
-                         lambda b, h, qi, ki, g=group: (b, ki, h // g, 0)),
-            pl.BlockSpec((1, block_k, 1, Dv),
-                         lambda b, h, qi, ki, g=group: (b, ki, h // g, 0)),
+            pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_k, D),
+                         lambda b, h, qi, ki, g=group: (b, h // g, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, Dv),
+                         lambda b, h, qi, ki, g=group: (b, h // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, Dv),
-                               lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, nq * block_q, Hq, Dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, Dv),
+                               lambda b, h, qi, ki: (b, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, nq * block_q, Dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, Dv), jnp.float32),   # acc
             pltpu.VMEM((block_q, 1), jnp.float32),    # running max m
@@ -135,4 +148,4 @@ def flash_attention(q, k, v, *, causal: bool = True, q_offset: int = 0,
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :Tq]
+    return out[:, :, :Tq].transpose(0, 2, 1, 3)

@@ -1,11 +1,16 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On the TPU target these dispatch to the compiled kernels; on this CPU
-container they run in ``interpret=True`` mode (the kernel body executed
-in Python), which is how the sweep tests validate them against ``ref.py``.
-``default_interpret()`` picks automatically from the backend.
+On a TPU backend these dispatch to the compiled kernels; on a CPU
+backend they run in ``interpret=True`` mode (the kernel body executed in
+Python), which is how the sweep tests validate them against ``ref.py``.
+``default_interpret()`` picks automatically from the backend unless an
+enclosing :func:`interpret_mode` says otherwise (a target pinned to the
+host CPU of a TPU machine interprets; one pinned to the chip compiles).
 """
 from __future__ import annotations
+
+import contextlib
+import contextvars
 
 import jax
 
@@ -15,7 +20,27 @@ from .moe_gather import (dispatch_indices, expert_glu as _expert_glu,
 from .ssd_scan import ssd_scan as _ssd_scan
 
 
+_INTERPRET: contextvars.ContextVar[bool | None] = contextvars.ContextVar(
+    "pallas_interpret", default=None)
+
+
+@contextlib.contextmanager
+def interpret_mode(flag: bool | None):
+    """Within the block, kernels called with ``interpret=None`` run
+    interpreted (``True``) or compiled (``False``); ``None`` restores the
+    backend default.  Read at trace time, so it also governs kernels
+    traced inside an enclosing ``jax.jit``."""
+    token = _INTERPRET.set(flag)
+    try:
+        yield
+    finally:
+        _INTERPRET.reset(token)
+
+
 def default_interpret() -> bool:
+    forced = _INTERPRET.get()
+    if forced is not None:
+        return forced
     return jax.default_backend() != "tpu"
 
 
@@ -56,4 +81,5 @@ def moe_dispatch_combine(x, gate_idx, gate_vals, w_up, w_down, *,
 
 
 __all__ = ["flash_attention", "ssd_scan", "expert_glu",
-           "moe_dispatch_combine", "dispatch_indices", "default_interpret"]
+           "moe_dispatch_combine", "dispatch_indices", "default_interpret",
+           "interpret_mode"]

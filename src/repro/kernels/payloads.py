@@ -8,7 +8,9 @@ state, activations are the dataflow).  Dialects:
 
 * ``"ref"``    — the pure-jnp oracle from :mod:`repro.kernels.ref`
   (bind it as ``op.fn``: the interpreter path and every probe verify
-  against it);
+  against it), run at full f32 matmul precision so that the oracle is
+  f32 on every backend (a TPU's default pass rounds f32 operands to
+  bf16);
 * ``"pallas"`` — the Pallas kernel via :mod:`repro.kernels.ops`
   (``interpret=None`` → interpret-mode off-TPU, compiled on TPU);
 * ``"numpy"``  — host NumPy, for the host-affine ops the paper maps to
@@ -32,6 +34,14 @@ import numpy as np
 from . import ops, ref
 
 PayloadTable = Mapping[str, Callable[..., Any]]
+
+
+def _highest(fn):
+    """``fn`` with its matmuls at full f32 precision."""
+    def run(*args):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args)
+    return run
 
 
 def bind_variants(op, table: PayloadTable,
@@ -59,6 +69,7 @@ def attention_payloads(k, v, *, causal: bool = True, q_offset: int = 0,
                        interpret: bool | None = None) -> dict:
     """Fused attention: activation is the query ``(B, Tq, Hq, D)``; the
     key/value streams (e.g. a decode KV cache) are closed over."""
+    @_highest
     def ref_fn(q):
         return ref.attention_ref(q, k, v, causal=causal, q_offset=q_offset)
 
@@ -74,6 +85,7 @@ def ssd_payloads(c, b, log_a, *, initial_state=None, chunk: int = 32,
     """SSD recurrence: activation is the value stream ``(B, T, H, P)``;
     the state/input projections and decay gates are closed over.  Only
     the sequence output flows (the carried state is layer-internal)."""
+    @_highest
     def ref_fn(x):
         y, _ = ref.ssd_scan_ref(c, b, x, log_a, initial_state=initial_state)
         return y
@@ -90,13 +102,16 @@ def moe_payloads(w_gate, w_up, w_down, *, capacity: int, top_k: int = 2,
                  interpret: bool | None = None) -> dict:
     """Routed MoE layer: activation ``(T, d)`` tokens; router + expert
     weights closed over.  Gating (softmax top-k, renormalized) is shared
-    jnp code so the dialects differ only in dispatch/combine."""
+    jnp code (at full precision, so both dialects route alike) and the
+    dialects differ only in dispatch/combine."""
+    @_highest
     def gates(x):
         logits = x.astype(jnp.float32) @ w_gate.astype(jnp.float32)
         gv, gi = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
         gv = (gv / gv.sum(-1, keepdims=True)).astype(x.dtype)
         return gi, gv
 
+    @_highest
     def ref_fn(x):
         gi, gv = gates(x)
         return ref.moe_dispatch_combine_ref(x, gi, gv, w_up, w_down,

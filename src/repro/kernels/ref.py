@@ -77,6 +77,20 @@ def ssd_scan_ref(c, b, v, log_a, *, initial_state=None):
 # ---------------------------------------------------------------------------
 
 
+def expert_glu_ref(x, w_up, w_down):
+    """Oracle for the fused expert GLU on capacity-padded tokens.
+
+    x: (E, cap, d); w_up: (E, d, 2F) ([..., :F] gate, [..., F:] up);
+    w_down: (E, F, d).  fp32 math, output in x.dtype.
+    """
+    h = jnp.einsum("ecd,edf->ecf", x.astype(jnp.float32),
+                   w_up.astype(jnp.float32))
+    g, u = jnp.split(h, 2, axis=-1)
+    y = jnp.einsum("ecf,efd->ecd", jax.nn.silu(g) * u,
+                   w_down.astype(jnp.float32))
+    return y.astype(x.dtype)
+
+
 def moe_dispatch_combine_ref(x, gate_idx, gate_vals, w_up, w_down, *,
                              capacity: int):
     """Oracle for the fused MoE expert-apply with capacity dropping.

@@ -703,7 +703,9 @@ def prefill(cfg, params, batch, max_len: int, shd: Policy = NO_POLICY):
             k = L.apply_rope(k, cs, sn)
             ck = jax.lax.dynamic_update_slice_in_dim(ck, k, 0, axis=1)
             cv = jax.lax.dynamic_update_slice_in_dim(cv, v, 0, axis=1)
-            a, _ = L.gqa_attention(sa["attn"], x, cfg, shd, positions=pos)
+            a, _ = L.gqa_attention(
+                sa["attn"], x, cfg, shd, positions=pos,
+                use_flash="pallas" if cfg.use_kernels else None)
             return h + a, (ssm_g, conv_g, ck, cv)
         h, (nssm, nconv, nk, nv) = jax.lax.scan(
             _maybe_remat(group_body, cfg), h,

@@ -25,7 +25,7 @@ from repro.models import model as M
 from repro.optim import adamw
 from repro.sharding import Policy
 from repro.train import trainer as T
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 mode, ckpt_dir = sys.argv[1], sys.argv[2]
 
@@ -40,7 +40,8 @@ tc = T.TrainConfig(opt=adamw.AdamWConfig(lr=1e-3, warmup_steps=2,
                                          total_steps=10))
 
 def make_mesh(shape):
-    return jax.make_mesh(shape, ("data", "model"))
+    return jax.make_mesh(shape, ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 def run_steps(params, opt, policy, mesh, start, n):
     step = T.jit_train_step(cfg, tc, policy,

@@ -190,6 +190,26 @@ def test_moe_sweep(T, d, E, K, F, cap, bm, bf, dtype):
                                rtol=5e-2 if dtype == jnp.bfloat16 else 2e-4)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("E,cap,d,F,bm,bf", [
+    (2, 32, 64, 32, 16, 16),
+    (3, 40, 32, 48, 16, 16),     # cap padded to the row block
+])
+def test_expert_glu_sweep(E, cap, d, F, bm, bf, dtype):
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    x = rand(ks[0], (E, cap, d), dtype)
+    w_up = rand(ks[1], (E, d, 2 * F), dtype) * d ** -0.5
+    w_down = rand(ks[2], (E, F, d), dtype) * F ** -0.5
+    out = ops.expert_glu(x, w_up, w_down, block_m=bm, block_f=bf,
+                         interpret=True)
+    expected = ref.expert_glu_ref(x, w_up, w_down)
+    assert out.shape == expected.shape and out.dtype == x.dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(expected, np.float32),
+                               atol=5e-2 if dtype == jnp.bfloat16 else 2e-4,
+                               rtol=5e-2 if dtype == jnp.bfloat16 else 2e-4)
+
+
 @settings(max_examples=15, deadline=None)
 @given(
     T=st.integers(8, 64),

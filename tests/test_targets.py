@@ -73,10 +73,20 @@ def test_default_registry_contains_builtins_and_devices():
     reg = default_registry()
     for name in ("numpy-eager", "xla-cpu", "pallas-interpret"):
         assert name in reg
-    devs = discover_devices()    # must never raise
+    devs = discover_devices()
+    assert devs and all(t.device is not None for t in devs)
     for t in devs:
         assert t.name in reg
     assert len(default_registry(devices=False)) == 3
+
+
+def test_discover_devices_raises_when_the_backend_fails(monkeypatch):
+    """A backend that cannot start must not silently drop its lanes."""
+    def broken():
+        raise RuntimeError("backend failed to initialise")
+    monkeypatch.setattr(jax, "devices", broken)
+    with pytest.raises(RuntimeError, match="initialise"):
+        discover_devices()
 
 
 def test_resolve_targets_forms():
@@ -185,7 +195,9 @@ def test_variant_rejected_falls_back_to_reference():
     g = _variant_chain(3, variants=variants)
     ex, prog = _compiled_on(binding, g, "alt")
     got = prog.run({0: (_x(),)})
-    assert set(prog.stats["variant_verified"].values()) == {"rejected"}
+    (verdict,) = set(prog.stats["variant_verified"].values())
+    assert verdict.startswith("rejected: output ")   # says which and why
+    assert "max_abs_err=1.000e+00" in verdict
     assert prog.stats["n_variant"] == 0
     assert results_bitwise_equal(ex.run_monolithic(g, {0: (_x(),)}), got)
 
@@ -237,6 +249,89 @@ def test_target_jit_policy_and_tolerance_gated_jit():
     (seg,) = prog.segments
     assert seg.mode == JIT
     assert prog.stats["jit_verified"][seg.index] in ("bitwise", "tolerance")
+
+
+_HOST = jax.devices("cpu")[0]
+
+
+@pytest.mark.parametrize("make", [
+    numpy_eager, xla_cpu, pallas_interpret,
+    lambda: device_target(_HOST),
+    lambda: device_target(_HOST, dialect="pallas", interpret=True),
+], ids=["numpy-eager", "xla-cpu", "pallas-interpret", "device", "device-pallas"])
+def test_builtin_target_outputs_land_on_declared_device(make):
+    """Every output of a lane lives where its target says: on
+    ``target.device``, or on the host for a target that declares none."""
+    tgt = make()
+    variants = {i: {"pallas": lambda v: jnp.tanh(v * jnp.float32(1.0))}
+                for i in range(3)}
+    g = _variant_chain(3, variants=variants)
+    _, prog = _compiled_on({"L": tgt}, g, "L")
+    prog.run({0: (_x(),)})                 # cold: probe + settle
+    got = prog.run({0: (_x(),)})           # warm: the served path
+    want = tgt.device if tgt.device is not None else _HOST
+    for out in got.values():
+        assert isinstance(out, (np.ndarray, jax.Array))
+        if isinstance(out, jax.Array):
+            assert out.devices() == {want}
+        else:
+            assert tgt.device is None      # host arrays only on host lanes
+    if tgt.jit:
+        assert [s.mode for s in prog.segments] == [JIT]
+
+
+def test_jit_hoisting_constants_keeps_weights_out_of_the_program():
+    """Closed-over weights are arguments of the jitted program, placed on
+    the given device, not constants baked into it; values are those of
+    ``jax.jit``."""
+    import warnings
+    from repro.core.hoist import jit_hoisting_constants
+    w = jnp.arange(64 * 64, dtype=jnp.float32).reshape(64, 64) / 4096.0
+
+    def f(x, scale):
+        return {"y": jnp.tanh(x @ w) * scale}
+
+    x = jnp.linspace(-1.0, 1.0, 8 * 64, dtype=jnp.float32).reshape(8, 64)
+    prev = jax.config.jax_captured_constants_warn_bytes
+    jax.config.update("jax_captured_constants_warn_bytes", 1024)
+    try:
+        with warnings.catch_warnings(record=True) as baked:
+            warnings.simplefilter("always")
+            want = jax.jit(f)(x, 2.0)
+        with warnings.catch_warnings(record=True) as hoisted:
+            warnings.simplefilter("always")
+            run = jit_hoisting_constants(f, _HOST)
+            got = run(x, 2.0)
+            again = run(x + 1.0, 3.0)        # same signature: no retrace
+    finally:
+        jax.config.update("jax_captured_constants_warn_bytes", prev)
+    captured = "constants were captured"
+    assert any(captured in str(m.message) for m in baked)
+    assert not any(captured in str(m.message) for m in hoisted)
+    assert np.array_equal(np.asarray(got["y"]), np.asarray(want["y"]))
+    assert got["y"].devices() == {_HOST}
+    assert np.array_equal(np.asarray(again["y"]),
+                          np.asarray(jax.jit(f)(x + 1.0, 3.0)["y"]))
+
+
+@pytest.mark.parametrize("flag", [True, False])
+def test_target_interpret_setting_reaches_the_kernels(flag):
+    """``Target.interpret`` governs how the served Pallas payloads run,
+    on the compiled path and in the profiler alike."""
+    from repro.kernels.ops import default_interpret
+    seen = []
+
+    def spy(v):
+        seen.append(default_interpret())   # read at trace time, as kernels do
+        return jnp.tanh(v * jnp.float32(1.0))
+
+    g = _variant_chain(1, variants={0: {"pallas": spy}})
+    tgt = device_target(_HOST, dialect="pallas", interpret=flag)
+    _, prog = _compiled_on({"L": tgt}, g, "L")
+    prog.run({0: (_x(),)})
+    MeasuredProfiler(warmup=0, iters=1, targets={"L": tgt}).profile(g)
+    assert seen and set(seen) == {flag}
+    assert default_interpret() is True     # restored outside the lane
 
 
 def test_targetless_segments_remain_strictly_bitwise():
