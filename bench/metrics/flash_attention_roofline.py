@@ -1,0 +1,6 @@
+"""``flash_attention``'s share of its roofline (see ``bench.roofline``)."""
+from bench.roofline import share
+
+
+def read(ctx):
+    return share(ctx, "flash_attention")
