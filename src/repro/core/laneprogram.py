@@ -71,6 +71,7 @@ from typing import Any, Callable, Mapping, Sequence
 import jax
 import numpy as np
 
+from repro import telemetry
 from repro.fault.manager import RecoverableError
 
 from .errors import ExecutionError, PULostError
@@ -628,11 +629,12 @@ class LaneProgram:
         results: list[dict[int, Any]] = seeds
 
         def exec_seg(seg: Segment, run: RunContext | None) -> None:
-            t0 = time.monotonic() if segment_timings is not None else 0.0
-            self._exec_segment(seg, results, ext, run)
+            with telemetry.span("lane.segment", lane=seg.lane,
+                                segment=seg.index) as sp:
+                self._exec_segment(seg, results, ext, run)
             if segment_timings is not None:
                 segment_timings.append(
-                    (seg.lane, tuple(seg.items), time.monotonic() - t0))
+                    (seg.lane, tuple(seg.items), sp.seconds))
 
         if self.serial_order is not None:
             # inherently serial: no cross-lane waits exist, so the
@@ -664,7 +666,8 @@ class LaneProgram:
                 for seg in self.lane_segments[pu]:
                     for d, dwhat in zip(seg.deps, seg.dep_whats):
                         if not done[d].is_set():
-                            run.wait(done[d], dwhat)
+                            with telemetry.span("lane.wait", lane=pu, on=d):
+                                run.wait(done[d], dwhat)
                     run.check_abort()
                     exec_seg(seg, run)
                     done[seg.index].set()

@@ -100,6 +100,7 @@ from typing import Any, Mapping, Sequence
 
 import numpy as np
 
+from .. import telemetry
 from .contention import ContentionModel
 from .costmodel import CostTable, EDGE_PUS, PUSpec
 from .dynamic import DynamicScheduler, RuntimeCondition
@@ -586,9 +587,10 @@ class Orchestrator:
                     "algorithm=/max_states= route the M >= 2 concurrent "
                     "search; a single-request concurrent plan is a solo "
                     "best-PU walk with nothing to route")
-        return self._plan_cached(
-            [(reg, 0) for reg in regs], hs, objective, mode,
-            algorithm, max_states)
+        with telemetry.span("orchestrator.plan", mode=mode, handles=len(hs)):
+            return self._plan_cached(
+                [(reg, 0) for reg in regs], hs, objective, mode,
+                algorithm, max_states)
 
     def _plan_cached(self, regs_progress: list[tuple[_Registration, int]],
                      hs: tuple[int, ...], objective: str, mode: str,
@@ -846,12 +848,14 @@ class Orchestrator:
         propagates the :class:`~repro.core.errors.PULostError` (frontier
         attached as ``err.partial``) to the caller.
         """
-        try:
-            return self._execute_once(plan, inputs, compile, policy, faults)
-        except PULostError as err:
-            if not recover:
-                raise
-            return self._recover(plan, inputs, err, policy, faults)
+        with telemetry.span("orchestrator.execute", kind=plan.kind):
+            try:
+                return self._execute_once(plan, inputs, compile, policy,
+                                          faults)
+            except PULostError as err:
+                if not recover:
+                    raise
+                return self._recover(plan, inputs, err, policy, faults)
 
     def _execute_once(self, plan: Plan, inputs, compile: bool,
                       policy: ExecutionPolicy | None,

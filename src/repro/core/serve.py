@@ -56,11 +56,11 @@ from __future__ import annotations
 import asyncio
 import dataclasses
 import json
-import time
 from typing import Mapping, Sequence
 
 import numpy as np
 
+from .. import telemetry
 from .errors import (ExecutionTimeoutError, FaultRetryExceededError,
                      InfeasibleScheduleError, PULostError)
 from .faults import ChaosTrace, ExecutionPolicy, FaultPlan
@@ -437,9 +437,10 @@ class ServingEngine:
                 self._release(rec.model, rec_h)
 
         def timed(fn, *args, **kw):
-            t = time.perf_counter()
-            out = fn(*args, **kw)
-            plan_ms.append((time.perf_counter() - t) * 1e3)
+            """One plan event: an admit, retire or re-plan of the set."""
+            with telemetry.span("orchestrator.plan", via=fn.__name__) as sp:
+                out = fn(*args, **kw)
+            plan_ms.append(sp.seconds * 1e3)
             return out
 
         def admit_due() -> bool:
@@ -540,15 +541,14 @@ class ServingEngine:
             orch.retire(h, self.objective, self.horizon_states)
             shed(rec, reason)
 
-        def recover(t_fail: float) -> None:
-            """One fault -> re-plan recovery cycle, timed wall-clock from
-            the catch to the re-planned active set."""
+        def recover() -> None:
+            """One fault -> re-plan recovery cycle: re-plan the active set
+            on the survivors."""
             nonlocal recoveries
             recoveries += 1
             for rec in inflight.values():
                 rec.recovered = True
             apply_health()
-            recovery_ms.append((time.perf_counter() - t_fail) * 1e3)
 
         def commit(handles, results, steps) -> None:
             """Fold executed results into the request frontiers, advance
@@ -607,11 +607,52 @@ class ServingEngine:
                     break
             return end
 
+        def on_fault(err, handles, frontiers, steps, window_pus,
+                     attempts: int) -> str:
+            """Handle a failed window execution after ``attempts`` earlier
+            retries: ``"recovered"`` (a PU was lost or its breaker opened;
+            the active set is re-planned), ``"retry"`` (re-execute the
+            window) or ``"shed"`` (the requests it names are shed)."""
+            nonlocal plan, retried
+            if isinstance(err, PULostError):
+                commit(handles, err.partial or frontiers, steps)
+                health.record_loss(err.pu, now)
+                recover()
+                return "recovered"
+            if isinstance(err, ExecutionTimeoutError):
+                opened = False
+                for lane in sorted(err.inflight) or window_pus:
+                    opened |= health.record_failure(lane, now, "timeout")
+                reason = "timeout"
+            else:
+                opened = err.lane is not None and health.record_failure(
+                    err.lane, now, "retry_exceeded")
+                reason = "fault"
+            retried += 1
+            for h in handles:
+                if h in inflight:
+                    inflight[h].retries += 1
+            if opened:
+                recover()
+                return "recovered"
+            if attempts < self.max_window_retries:
+                return "retry"     # discard + re-execute the window
+            named = err.request if reason == "fault" else None
+            if named is not None and 0 <= named < len(handles) \
+                    and handles[named] in inflight:
+                shed_inflight(handles[named], "fault")
+            else:
+                for h in handles:
+                    if h in inflight:
+                        shed_inflight(h, reason)
+            plan = None
+            return "shed"
+
         def exec_window(end: int) -> None:
             """Really execute plan steps [cursor:end) through the fault
             runtime, with in-loop retries, breaker-driven quarantine +
             fleet-wide re-plan, and typed shedding."""
-            nonlocal plan, retried, exec_wall
+            nonlocal exec_wall
             handles = plan.handles
             steps = list(plan.schedule.steps[cursor:end])
             graphs = [orch._reg(h).graph for h in handles]
@@ -629,82 +670,46 @@ class ServingEngine:
                 frontiers = [dict(inflight[h].results) if h in inflight
                              else {} for h in handles]
                 timings: list = []
-                tw = time.perf_counter()
-                try:
-                    if self.compile_exec:
-                        seg_t: list = []
-                        prog = orch.executor.compile_concurrent(
-                            graphs, sub, completed=frontiers, partial=True)
-                        results = prog.run(
-                            ext, policy=self.exec_policy, faults=faults,
-                            estimate=est, completed=frontiers,
-                            segment_timings=seg_t)
-                        timings = [(lane, r, i, dt / max(len(items), 1))
-                                   for lane, items, dt in seg_t
-                                   for (r, i) in items]
-                    else:
-                        results = orch.executor.run_concurrent(
-                            graphs, sub, ext, completed=frontiers,
-                            policy=self.exec_policy, faults=faults,
-                            estimate=est, partial=True,
-                            op_timings=timings)
-                except PULostError as err:
-                    exec_wall += time.perf_counter() - tw
-                    t_fail = time.perf_counter()
-                    commit(handles, err.partial or frontiers, steps)
-                    health.record_loss(err.pu, now)
-                    recover(t_fail)
-                    return
-                except ExecutionTimeoutError as err:
-                    exec_wall += time.perf_counter() - tw
-                    t_fail = time.perf_counter()
-                    lanes = sorted(err.inflight) or window_pus
-                    opened = False
-                    for lane in lanes:
-                        opened |= health.record_failure(
-                            lane, now, "timeout")
-                    attempts += 1
-                    retried += 1
-                    for h in handles:
-                        if h in inflight:
-                            inflight[h].retries += 1
-                    if opened:
-                        recover(t_fail)
-                        return
-                    if attempts <= self.max_window_retries:
-                        continue       # discard + re-execute the window
-                    for h in handles:
-                        if h in inflight:
-                            shed_inflight(h, "timeout")
-                    plan = None
-                    return
-                except FaultRetryExceededError as err:
-                    exec_wall += time.perf_counter() - tw
-                    t_fail = time.perf_counter()
-                    opened = err.lane is not None and health.record_failure(
-                        err.lane, now, "retry_exceeded")
-                    attempts += 1
-                    retried += 1
-                    for h in handles:
-                        if h in inflight:
-                            inflight[h].retries += 1
-                    if opened:
-                        recover(t_fail)
-                        return
-                    if attempts <= self.max_window_retries:
+                err = None
+                with telemetry.span("orchestrator.execute", kind="window",
+                                    steps=len(steps)) as ex:
+                    try:
+                        if self.compile_exec:
+                            seg_t: list = []
+                            prog = orch.executor.compile_concurrent(
+                                graphs, sub, completed=frontiers,
+                                partial=True)
+                            results = prog.run(
+                                ext, policy=self.exec_policy, faults=faults,
+                                estimate=est, completed=frontiers,
+                                segment_timings=seg_t)
+                            timings = [(lane, r, i, dt / max(len(items), 1))
+                                       for lane, items, dt in seg_t
+                                       for (r, i) in items]
+                        else:
+                            results = orch.executor.run_concurrent(
+                                graphs, sub, ext, completed=frontiers,
+                                policy=self.exec_policy, faults=faults,
+                                estimate=est, partial=True,
+                                op_timings=timings)
+                    except (PULostError, ExecutionTimeoutError,
+                            FaultRetryExceededError) as e:
+                        err = e
+                exec_wall += ex.seconds
+                if err is not None:
+                    # from the catch to the re-planned active set, when
+                    # the fault ends in a recovery
+                    with telemetry.span("serve.fault",
+                                        error=type(err).__name__) as fs:
+                        outcome = on_fault(err, handles, frontiers, steps,
+                                           window_pus, attempts)
+                    if outcome == "recovered":
+                        recovery_ms.append(fs.seconds * 1e3)
+                    if outcome == "retry":
+                        attempts += 1
                         continue
-                    if err.request is not None \
-                            and 0 <= err.request < len(handles) \
-                            and handles[err.request] in inflight:
-                        shed_inflight(handles[err.request], "fault")
-                    else:
-                        for h in handles:
-                            if h in inflight:
-                                shed_inflight(h, "fault")
-                    plan = None
                     return
                 # -- success ------------------------------------------------
-                exec_wall += time.perf_counter() - tw
                 slot_model = [inflight[h].model if h in inflight else None
                               for h in handles]
                 commit(handles, results, steps)

@@ -26,6 +26,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
 from ..sharding import Policy, NO_POLICY
 from . import layers as L
 
@@ -546,12 +547,18 @@ def prefill(cfg, params, batch, max_len: int, shd: Policy = NO_POLICY):
     second pass of the per-layer K/V projections (cheap relative to
     attention itself) — a deliberate simplification that keeps prefill a
     single scan-over-layers program.
+
+    Spans (``repro.telemetry``): ``model.prefill.setup`` (embedding,
+    positions, empty cache) and ``model.prefill.logits``; the layer scan
+    between them is the rest of the caller's span.  Under ``jax.jit``
+    they fire at trace time.
     """
-    h = _embed_in(cfg, params, batch, shd)
-    B, T = h.shape[:2]
-    pos = _positions(cfg, batch, T)
-    bp = cfg.block_pattern
-    cache = init_cache(cfg, B, max_len)
+    with telemetry.span("model.prefill.setup"):
+        h = _embed_in(cfg, params, batch, shd)
+        B, T = h.shape[:2]
+        pos = _positions(cfg, batch, T)
+        bp = cfg.block_pattern
+        cache = init_cache(cfg, B, max_len)
 
     if bp in ("dense", "moe"):
         def body(h, xs):
@@ -717,4 +724,6 @@ def prefill(cfg, params, batch, max_len: int, shd: Policy = NO_POLICY):
     else:
         raise ValueError(bp)
 
-    return _logits(cfg, params, h[:, -1:], shd), cache
+    with telemetry.span("model.prefill.logits"):
+        logits = _logits(cfg, params, h[:, -1:], shd)
+    return logits, cache

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from .. import telemetry
 from ..models import model as M
 from ..sharding import Policy
 from ..train.trainer import batch_pspecs, param_shardings
@@ -118,6 +119,8 @@ class Engine:
         default_factory=dict, repr=False, compare=False)
     _jit_decode: Any = dataclasses.field(
         default=None, repr=False, compare=False)
+    # generate calls so far: the ``call`` attribute of their spans
+    _calls: int = dataclasses.field(default=0, repr=False, compare=False)
 
     def decode_step_fn(self):
         """The engine's single jitted decode step.
@@ -141,17 +144,34 @@ class Engine:
 
     def generate(self, prompt_tokens, max_new: int = 16,
                  max_len: int | None = None):
-        """Greedy batched generation.  prompt_tokens: (B, T) int32."""
+        """Greedy batched generation.  prompt_tokens: (B, T) int32.
+
+        Spans (``repro.telemetry``): ``engine.generate`` around the call,
+        ``engine.prefill`` around the prompt's forward pass, and
+        ``engine.decode`` around the token loop, one ``engine.decode_step``
+        per step's dispatch and pick."""
         B, T = prompt_tokens.shape
         max_len = max_len or (T + max_new)
-        logits, cache = M.prefill(self.cfg, self.params,
-                                  {"tokens": prompt_tokens},
-                                  max_len=max_len, shd=self.policy)
-        outs = []
-        tok = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
-        step = self.decode_step_fn()
-        for _ in range(max_new):
-            outs.append(tok)
-            logits, cache = step(self.params, cache, {"tokens": tok})
-            tok = jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
-        return jnp.concatenate(outs, axis=1)
+        self._calls += 1
+        with telemetry.span("engine.generate", batch=B, prompt=T,
+                            max_new=max_new, call=self._calls):
+            with telemetry.span("engine.prefill"):
+                logits, cache = M.prefill(self.cfg, self.params,
+                                          {"tokens": prompt_tokens},
+                                          max_len=max_len, shd=self.policy)
+            with telemetry.span("engine.decode"):
+                outs = []
+                tok = _greedy(logits)
+                step = self.decode_step_fn()
+                for i in range(max_new):
+                    outs.append(tok)
+                    with telemetry.span("engine.decode_step", i=i):
+                        logits, cache = step(self.params, cache,
+                                             {"tokens": tok})
+                        tok = _greedy(logits)
+                return jnp.concatenate(outs, axis=1)
+
+
+def _greedy(logits):
+    """The next token of each row: the argmax of its last logits."""
+    return jnp.argmax(logits[:, -1], axis=-1)[:, None].astype(jnp.int32)
