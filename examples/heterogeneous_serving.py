@@ -115,7 +115,8 @@ engine = Engine(cfg=cfg, params=params, policy=Policy())
 prompts = jnp.asarray(np.random.default_rng(0).integers(
     0, cfg.vocab, (args.batch, 16), dtype=np.int32))
 out = engine.generate(prompts, max_new=8)
-out = engine.generate(prompts, max_new=8)   # decode step: no re-trace
+out = engine.generate(prompts, max_new=8)   # prefill, decode step: no re-trace
 print(f"\nserved batch: prompts {prompts.shape} -> generated {out.shape} "
-      f"(decode-step traces: {sum(engine.decode_trace_counts.values())} "
+      f"(prefill traces: {sum(engine.prefill_trace_counts.values())}, "
+      f"decode-step traces: {sum(engine.decode_trace_counts.values())} "
       f"across 2 generate calls)")

@@ -2,7 +2,8 @@
 
 ``jit_decode_step`` / ``jit_prefill`` are what the dry-run lowers for the
 ``decode_*`` / ``prefill_*`` shape cells.  The engine's ``generate`` drives
-real batched requests for the examples.
+real batched requests: one jitted prefill program and one jitted decode
+step per engine, each traced once per argument shape.
 """
 from __future__ import annotations
 
@@ -119,6 +120,13 @@ class Engine:
         default_factory=dict, repr=False, compare=False)
     _jit_decode: Any = dataclasses.field(
         default=None, repr=False, compare=False)
+    # the same for the prompt's forward pass, keyed (tokens shape,
+    # max_len): an eager ``M.prefill`` re-traced its layer scan and
+    # reloaded it from the compilation cache on every call
+    prefill_trace_counts: dict = dataclasses.field(
+        default_factory=dict, repr=False, compare=False)
+    _jit_prefill: Any = dataclasses.field(
+        default=None, repr=False, compare=False)
     # generate calls so far: the ``call`` attribute of their spans
     _calls: int = dataclasses.field(default=0, repr=False, compare=False)
 
@@ -142,12 +150,33 @@ class Engine:
             self._jit_decode = jax.jit(step)
         return self._jit_decode
 
+    def prefill_fn(self):
+        """The engine's single jitted prefill: ``(params, batch, max_len)``
+        -> (last-position logits, filled cache), ``max_len`` static.
+
+        The weights are an argument, never closed over, so they are not
+        baked into the program as constants.
+        """
+        if self._jit_prefill is None:
+            def prefill(params, batch, max_len):
+                key = (tuple(batch["tokens"].shape), max_len)
+                self.prefill_trace_counts[key] = \
+                    self.prefill_trace_counts.get(key, 0) + 1
+                return M.prefill(self.cfg, params, batch, max_len=max_len,
+                                 shd=self.policy)
+            self._jit_prefill = jax.jit(prefill, static_argnames="max_len")
+        return self._jit_prefill
+
     def generate(self, prompt_tokens, max_new: int = 16,
                  max_len: int | None = None):
         """Greedy batched generation.  prompt_tokens: (B, T) int32.
 
+        Runs ``prefill_fn()`` over the prompt, then ``decode_step_fn()``
+        once per new token.
+
         Spans (``repro.telemetry``): ``engine.generate`` around the call,
-        ``engine.prefill`` around the prompt's forward pass, and
+        ``engine.prefill`` around the prompt's forward pass (with
+        ``model.prefill.*`` under it only on a call that traces), and
         ``engine.decode`` around the token loop, one ``engine.decode_step``
         per step's dispatch and pick."""
         B, T = prompt_tokens.shape
@@ -156,9 +185,8 @@ class Engine:
         with telemetry.span("engine.generate", batch=B, prompt=T,
                             max_new=max_new, call=self._calls):
             with telemetry.span("engine.prefill"):
-                logits, cache = M.prefill(self.cfg, self.params,
-                                          {"tokens": prompt_tokens},
-                                          max_len=max_len, shd=self.policy)
+                logits, cache = self.prefill_fn()(
+                    self.params, {"tokens": prompt_tokens}, max_len=max_len)
             with telemetry.span("engine.decode"):
                 outs = []
                 tok = _greedy(logits)
