@@ -25,11 +25,15 @@ class ModelConfig:
     n_kv_heads: int
     d_ff: int
     vocab: int
-    block_pattern: str = "dense"     # dense|moe|mla_moe|xlstm|zamba2|encdec
+    block_pattern: str = "dense"     # dense|moe|mla_moe|xlstm|zamba2|encdec|nemotron_h
+    # nemotron_h: one mixer a layer, read from this string (M Mamba2,
+    # E experts, * attention); the first ``n_layers`` characters are run
+    layer_pattern: str = ""
     d_head: int | None = None
     qk_norm: bool = False
     causal: bool = True
     rope_theta: float = 5e5
+    rope: bool = True                # False: attention without positions (NoPE)
     mrope: bool = False
     mrope_sections: tuple = (16, 24, 24)
     # --- MLA (deepseek) ---
@@ -49,6 +53,13 @@ class ModelConfig:
     moe_renorm: bool = True
     moe_group_size: int = 512       # dispatch-group tokens (shards over data)
     aux_loss_coef: float = 0.01
+    # nemotron_h's sigmoid router scales its weights by this; the shared
+    # expert's own width
+    moe_routed_scale: float = 1.0
+    moe_shared_d_ff: int = 0
+    # the experts this chip holds: [offset, offset + held); 0 = all
+    moe_held: int = 0
+    moe_expert_offset: int = 0
     # --- SSM / Mamba2 (zamba2) ---
     ssm_state: int = 64
     ssm_expand: int = 2
@@ -56,6 +67,8 @@ class ModelConfig:
     ssm_conv: int = 4
     ssm_chunk: int = 256
     ssm_headdim: int = 64
+    ssm_n_heads: int = 0             # explicit Mamba2 heads; 0 = from expand
+    ssm_norm_eps: float = 1e-6       # the gated norm's eps
     zamba_attn_every: int = 6
     # --- xLSTM ---
     xlstm_expand: int = 2
@@ -86,11 +99,22 @@ class ModelConfig:
     # derived SSM dims
     @property
     def ssm_d_inner(self) -> int:
+        if self.ssm_n_heads:
+            return self.ssm_n_heads * self.ssm_headdim
         return self.ssm_expand * self.d_model
 
     @property
     def ssm_heads(self) -> int:
-        return self.ssm_d_inner // self.ssm_headdim
+        return self.ssm_n_heads or self.ssm_d_inner // self.ssm_headdim
+
+    @property
+    def layer_kinds(self) -> str:
+        """The mixer of each layer run, for ``nemotron_h``."""
+        return self.layer_pattern[:self.n_layers]
+
+    @property
+    def experts_held(self) -> int:
+        return self.moe_held or self.n_experts
 
     @property
     def xlstm_d_inner(self) -> int:
@@ -154,6 +178,18 @@ class ModelConfig:
                    + self.ssm_conv * conv_dim + di * d)
             n += self.n_layers * per
             n += attn_params()  # one shared attention block
+        elif self.block_pattern == "nemotron_h":
+            kinds = self.layer_kinds
+            di, N, G, H = (self.ssm_d_inner, self.ssm_state, self.ssm_groups,
+                           self.ssm_heads)
+            conv_dim = di + 2 * N * G
+            mamba = (d * (2 * di + 2 * N * G + H) + (self.ssm_conv + 1) * conv_dim
+                     + 3 * H + di + di * d)
+            moe = (d * self.n_experts + self.n_experts
+                   + self.experts_held * 2 * d * self.moe_d_ff
+                   + 2 * d * self.moe_shared_d_ff)
+            n += (kinds.count("M") * mamba + kinds.count("E") * moe
+                  + kinds.count("*") * attn_params() + len(kinds) * d)
         return int(n)
 
     def reduced(self) -> "ModelConfig":
@@ -189,6 +225,11 @@ class ModelConfig:
             r = dataclasses.replace(r, n_layers=4)
         if r.block_pattern == "xlstm":
             r = dataclasses.replace(r, n_layers=4)
+        if r.block_pattern == "nemotron_h":
+            # every mixer kind, 8 routed experts of which this chip holds 4
+            r = dataclasses.replace(r, n_layers=6, n_experts=8, moe_held=4,
+                                    moe_shared_d_ff=128, ssm_n_heads=8,
+                                    ssm_groups=2)
         return r
 
 

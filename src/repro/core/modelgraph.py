@@ -126,13 +126,14 @@ def model_op_graph(cfg, *, kind: str = "train", batch: int = 8,
         add(_norm(f"L{i}.ln2", NT, d, dtb))
         fork = add(_mm(f"L{i}.router", NT, d, cfg.n_experts, 4))
         # routed branch: dispatch gather, expert GEMMs (active experts
-        # only: top-k of tokens), combine scatter
+        # only: top-k of tokens, the held experts' share), combine scatter
         ff = cfg.moe_d_ff
-        tok_k = NT * cfg.moe_top_k
+        gated = cfg.block_pattern != "nemotron_h"     # relu^2: no gate
+        tok_k = max(NT * cfg.moe_top_k * cfg.experts_held // cfg.n_experts, 1)
         disp = add(FusedOp(name=f"L{i}.dispatch", kind="gather",
                            in_shapes=((NT, d), (tok_k,)),
                            out_shape=(tok_k, d), dtype_bytes=dtb), after=fork)
-        add(_mm(f"L{i}.exp_up", tok_k, d, 2 * ff, dtb))
+        add(_mm(f"L{i}.exp_up", tok_k, d, (1 + gated) * ff, dtb))
         add(_act(f"L{i}.exp_act", tok_k, ff, dtb))
         add(_mm(f"L{i}.exp_down", tok_k, ff, d, dtb))
         comb = add(FusedOp(name=f"L{i}.combine", kind="scatter",
@@ -140,11 +141,11 @@ def model_op_graph(cfg, *, kind: str = "train", batch: int = 8,
                            out_shape=(NT, d), dtype_bytes=dtb))
         join_srcs = [comb]
         if cfg.n_shared_experts:
-            sh_up = add(_mm(f"L{i}.shared_up", NT, d,
-                            2 * ff * cfg.n_shared_experts, dtb), after=fork)
-            add(_act(f"L{i}.shared_act", NT, ff * cfg.n_shared_experts, dtb))
-            sh_dn = add(_mm(f"L{i}.shared_down", NT,
-                            ff * cfg.n_shared_experts, d, dtb))
+            sff = cfg.moe_shared_d_ff or ff * cfg.n_shared_experts
+            sh_up = add(_mm(f"L{i}.shared_up", NT, d, (1 + gated) * sff, dtb),
+                        after=fork)
+            add(_act(f"L{i}.shared_act", NT, sff, dtb))
+            sh_dn = add(_mm(f"L{i}.shared_down", NT, sff, d, dtb))
             join_srcs.append(sh_dn)
         add(FusedOp(name=f"L{i}.moe_add", kind="add",
                     in_shapes=((NT, d),) * 2, out_shape=(NT, d),
@@ -224,6 +225,9 @@ def model_op_graph(cfg, *, kind: str = "train", batch: int = 8,
             mamba_layer(i)
             if (i + 1) % cfg.zamba_attn_every == 0:
                 gqa_layer(i, prefix="shared.")
+    elif bp == "nemotron_h":
+        for i, kind in enumerate(cfg.layer_kinds):
+            {"M": mamba_layer, "E": moe_mlp, "*": gqa_layer}[kind](i)
     else:
         raise ValueError(bp)
 

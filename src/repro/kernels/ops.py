@@ -14,6 +14,7 @@ import contextvars
 
 import jax
 
+from .expert_mlp import block_layout, expert_mlp as _expert_mlp
 from .flash_attention import flash_attention as _flash_attention
 from .moe_gather import (dispatch_indices, expert_glu as _expert_glu,
                          moe_dispatch_combine as _moe_dispatch_combine)
@@ -70,6 +71,15 @@ def expert_glu(x, w_up, w_down, *, block_m: int = 128, block_f: int = 256,
                        interpret=interpret)
 
 
+def expert_mlp(x, w_up, w_down, block_group, n_blocks, layer=0, *,
+               block_m: int, block_f: int = 512,
+               interpret: bool | None = None):
+    if interpret is None:
+        interpret = default_interpret()
+    return _expert_mlp(x, w_up, w_down, block_group, n_blocks, layer,
+                       block_m=block_m, block_f=block_f, interpret=interpret)
+
+
 def moe_dispatch_combine(x, gate_idx, gate_vals, w_up, w_down, *,
                          capacity: int, block_m: int = 128,
                          block_f: int = 256, interpret: bool | None = None):
@@ -80,6 +90,6 @@ def moe_dispatch_combine(x, gate_idx, gate_vals, w_up, w_down, *,
                                  block_f=block_f, interpret=interpret)
 
 
-__all__ = ["flash_attention", "ssd_scan", "expert_glu",
-           "moe_dispatch_combine", "dispatch_indices", "default_interpret",
-           "interpret_mode"]
+__all__ = ["flash_attention", "ssd_scan", "expert_glu", "expert_mlp",
+           "block_layout", "moe_dispatch_combine", "dispatch_indices",
+           "default_interpret", "interpret_mode"]

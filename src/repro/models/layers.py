@@ -204,7 +204,8 @@ def gqa_init(key, cfg, dtype) -> dict:
 
 def gqa_attention(p, x, cfg, shd: Policy, *, positions, cache=None,
                   use_flash: bool | None = None):
-    """Returns (out, new_cache).  cache = dict(k, v, len) for decode."""
+    """Returns (out, new_cache).  cache = dict(k, v, len) for decode;
+    without one, new_cache is this call's dict(k, v) after positions."""
     B, T, d = x.shape
     dh = cfg.d_head
     q = (x @ p["wq"]).reshape(B, T, cfg.n_heads, dh)
@@ -216,16 +217,18 @@ def gqa_attention(p, x, cfg, shd: Policy, *, positions, cache=None,
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"])
         k = rms_norm(k, p["k_norm"])
-    if cfg.mrope:
-        cos, sin = mrope_cos_sin(positions, dh, cfg.rope_theta,
-                                 cfg.mrope_sections)
-    else:
-        cos, sin = rope_cos_sin(positions[0] if positions.ndim == 3
-                                else positions, dh, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cfg.rope:
+        if cfg.mrope:
+            cos, sin = mrope_cos_sin(positions, dh, cfg.rope_theta,
+                                     cfg.mrope_sections)
+        else:
+            cos, sin = rope_cos_sin(positions[0] if positions.ndim == 3
+                                    else positions, dh, cfg.rope_theta)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
-    new_cache = None
+    # without a cache: this call's (k, v), for a prefill to keep
+    new_cache = {"k": k, "v": v}
     if cache is not None:
         # decode: insert k/v at cache['len'], attend over the full cache
         idx = cache["len"]
@@ -473,6 +476,108 @@ def moe_block(p, x, cfg, shd: Policy):
     return shd.constrain(out, "batch", "seq_act", "embed", name="moe_out"), aux
 
 
+def held_moe_init(key, cfg, dtype) -> dict:
+    """Router over all ``n_experts``, its choice-only bias, the held
+    experts' relu^2 weights as (held, F, d) each, and the shared expert."""
+    d, ff, n = cfg.d_model, cfg.moe_d_ff, cfg.experts_held
+    ks = split(key, 5)
+    return {
+        "router": dense_init(ks[0], d, cfg.n_experts, jnp.float32),
+        "router_bias": jnp.zeros((cfg.n_experts,), jnp.float32),
+        "w_up": (jax.random.normal(ks[1], (n, ff, d), jnp.float32)
+                 / math.sqrt(d)).astype(dtype),
+        "w_down": (jax.random.normal(ks[2], (n, ff, d), jnp.float32)
+                   / math.sqrt(ff)).astype(dtype),
+        "shared": {"wi": dense_init(ks[3], d, cfg.moe_shared_d_ff, dtype),
+                   "wo": dense_init(ks[4], cfg.moe_shared_d_ff, d, dtype)},
+    }
+
+
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def sigmoid_route(p, x, cfg):
+    """x (N, d) -> (experts (N, K), weights (N, K) f32): ``s = sigmoid(x
+    W_r)`` in f32 over all experts, the top ``moe_top_k`` by ``s +
+    router_bias`` (the bias chooses, it does not weigh), weighted by
+    ``s`` normalised to sum 1 and scaled by ``moe_routed_scale``."""
+    s = jax.nn.sigmoid(x.astype(jnp.float32) @ p["router"])
+    _, idx = jax.lax.top_k(s + p["router_bias"], cfg.moe_top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    return idx, w / (w.sum(-1, keepdims=True) + 1e-20) * cfg.moe_routed_scale
+
+
+def held_moe_block(p, x, cfg, shd: Policy, *, use_kernel: bool = False,
+                   layer=None):
+    """Dropless MoE layer of one chip that holds experts
+    ``[moe_expert_offset, + experts_held)`` of ``n_experts`` (Nemotron-H).
+
+    The router (``sigmoid_route``) runs in f32 over all experts.  The
+    (token, expert) pairs are sorted by held expert; pairs routed to
+    experts held elsewhere add nothing here.  Every pair that lands on a
+    held expert is computed, ``relu(x W_up^T)^2 W_down``: with
+    ``use_kernel`` by the Pallas ``expert_mlp`` over row blocks padded per
+    expert, else by ``jax.lax.ragged_dot``.  The shared relu^2 expert is
+    added for every token.  With ``layer``, ``w_up`` and ``w_down`` are
+    the stacks of every MoE layer and ``layer`` picks this one's (the
+    kernel reads the stack in place).
+
+    Returns (out, stats): stats int32 (3,) = the pairs on held experts,
+    the most on one held expert, and the held experts with any.
+    """
+    B, T, d = x.shape
+    N, K, n = B * T, cfg.moe_top_k, cfg.experts_held
+    rows = N * min(K, n)              # at most this many pairs are held
+    xf = x.reshape(N, d)
+    with jax.named_scope("moe.route"):
+        idx, w = sigmoid_route(p, xf, cfg)                       # (N, K)
+        local = idx.reshape(-1) - cfg.moe_expert_offset
+        group = jnp.where((local >= 0) & (local < n), local, n)  # n: elsewhere
+        order = jnp.argsort(group, stable=True)[:rows].astype(jnp.int32)
+        sizes = jnp.zeros((n + 1,), jnp.int32).at[group].add(1)[:n]
+        # each pair's place among the sorted ones
+        rank = jnp.zeros((N * K,), jnp.int32).at[order].set(
+            jnp.arange(rows, dtype=jnp.int32))
+        xz = jnp.concatenate([xf, jnp.zeros((1, d), xf.dtype)])  # N: zeros
+    with jax.named_scope("moe.experts"):
+        if use_kernel:
+            from ..kernels import ops as Kn
+            from ..kernels.expert_mlp import block_rows
+            per_expert = N * K // cfg.n_experts
+            bm = min(128, max(block_rows(x.dtype),
+                              1 << max(per_expert - 1, 0).bit_length()))
+            place, block_group, n_blocks, padded = Kn.block_layout(
+                sizes, rows, bm)
+            src = jnp.full((padded,), N, jnp.int32).at[place].set(
+                order // K, mode="drop")
+            y = Kn.expert_mlp(xz[src], p["w_up"], p["w_down"], block_group,
+                              n_blocks, 0 if layer is None else layer,
+                              block_m=bm)
+        else:
+            w_up, w_down = p["w_up"], p["w_down"]
+            if layer is not None:
+                w_up, w_down = w_up[layer], w_down[layer]
+            xs = xz[order // K]
+            h = jax.lax.ragged_dot(xs, jnp.swapaxes(w_up, 1, 2), sizes)
+            y = jax.lax.ragged_dot(_relu2(h).astype(x.dtype), w_down, sizes)
+            place = jnp.arange(rows, dtype=jnp.int32)
+        # the pairs held elsewhere add nothing (a select: the rows of
+        # blocks the kernel skipped are undefined)
+        held = (group < n).reshape(N, K)
+        slot = jnp.where(held, place[rank].reshape(N, K), 0)
+        routed = jnp.zeros((N, d), jnp.float32)
+        for k in range(K):
+            routed += jnp.where(held[:, k, None], y[slot[:, k]].astype(
+                jnp.float32) * w[:, k, None], 0.0)
+    with jax.named_scope("moe.shared"):
+        shared = _relu2(xf @ p["shared"]["wi"]) @ p["shared"]["wo"]
+    out = (routed.astype(x.dtype) + shared).reshape(B, T, d)
+    stats = jnp.stack([sizes.sum(), sizes.max(), (sizes > 0).sum()])
+    return (shd.constrain(out, "batch", "seq_act", "embed", name="moe_out"),
+            stats.astype(jnp.int32))
+
+
 # ---------------------------------------------------------------------------
 # chunked gated linear recurrence — shared by Mamba2 (SSD) and mLSTM
 # ---------------------------------------------------------------------------
@@ -576,7 +681,10 @@ def mamba2_init(key, cfg, dtype) -> dict:
 def mamba2_block(p, x, cfg, shd: Policy, *, state=None,
                  use_kernel: bool = False):
     """Mamba-2 (SSD).  state = dict(ssm (B,H,N,P), conv (B, k-1, convdim))
-    for single-step decode; None for full-sequence training."""
+    for single-step decode; None for a full sequence, whose new state is
+    the final SSM state and the convolution's last k-1 inputs.  Heads
+    share B and C in ``ssm_groups`` groups, and the gated RMS norm is
+    taken per group."""
     B, T, d = x.shape
     di, H, N, G = cfg.ssm_d_inner, cfg.ssm_heads, cfg.ssm_state, cfg.ssm_groups
     P = di // H
@@ -591,9 +699,9 @@ def mamba2_block(p, x, cfg, shd: Policy, *, state=None,
         xbc = jnp.einsum("bkc,kc->bc", conv_in[:, -cfg.ssm_conv:],
                          p["conv_w"])[:, None, :] + p["conv_b"]
     else:
-        new_conv = None
         pad = jnp.zeros((B, cfg.ssm_conv - 1, conv_dim), xbc.dtype)
         xin = jnp.concatenate([pad, xbc], axis=1)
+        new_conv = xin[:, T:]                  # the last k-1 inputs
         xbc = sum(xin[:, i:i + T] * p["conv_w"][i] for i in range(cfg.ssm_conv))
         xbc = xbc + p["conv_b"]
     xbc = jax.nn.silu(xbc)
@@ -617,14 +725,21 @@ def mamba2_block(p, x, cfg, shd: Policy, *, state=None,
     elif use_kernel:
         from ..kernels import ops as K
         y, S = K.ssd_scan(Ch, Bh, xdt, log_a, chunk=cfg.ssm_chunk)
-        new_state = {"ssm": S, "conv": None}
+        new_state = {"ssm": S, "conv": new_conv}
     else:
         y, S = chunked_linear_recurrence(Ch, Bh, xdt, log_a,
                                          chunk=cfg.ssm_chunk)
-        new_state = {"ssm": S, "conv": None}
+        new_state = {"ssm": S, "conv": new_conv}
     y = y + xs * p["D"][None, None, :, None].astype(xs.dtype)
     y = y.reshape(B, y.shape[1], di)
-    y = rms_norm(y * jax.nn.silu(z[:, :y.shape[1]]), p["norm_w"])
+    y = y * jax.nn.silu(z[:, :y.shape[1]])
+    if G == 1:
+        y = rms_norm(y, p["norm_w"], cfg.ssm_norm_eps)
+    else:
+        # the gated norm is taken over each group's di / G channels
+        w = p["norm_w"].reshape(G, di // G)
+        y = rms_norm(y.reshape(B, Tx, G, di // G), w,
+                     cfg.ssm_norm_eps).reshape(B, Tx, di)
     out = y @ p["out_proj"]
     return shd.constrain(out, "batch", "seq_act", "embed", name="ssm_out"), new_state
 

@@ -13,9 +13,16 @@ Every assigned architecture maps to one of five block patterns:
 * ``xlstm``    — alternating mLSTM / sLSTM pairs
 * ``zamba2``   — Mamba2 backbone + one *shared* GQA attention block applied
                  every ``zamba_attn_every`` layers
+* ``nemotron_h`` — one mixer a layer, Mamba2, held-expert MoE or GQA
+                 without positions, as ``cfg.layer_pattern`` says
+                 (nemotron-3-nano)
 
-All patterns scan over stacked layer parameters so HLO size (and CPU
-compile time for the 512-device dry-run) is depth-independent.
+All patterns but ``nemotron_h`` scan over stacked layer parameters so HLO
+size (and CPU compile time for the 512-device dry-run) is
+depth-independent.  ``nemotron_h`` stacks each mixer kind's parameters
+and unrolls its layers: its pattern is not periodic, and its runs of one
+kind are one layer long, so a scan per run would be as long as the
+unrolled program.
 """
 from __future__ import annotations
 
@@ -33,6 +40,83 @@ from . import layers as L
 
 def _stack_init(init_fn, key, n: int):
     return jax.vmap(init_fn)(jax.random.split(key, n))
+
+
+# nemotron_h: each mixer kind's stacked parameters
+_KIND_BLOCKS = {"M": "mamba_blocks", "E": "moe_blocks", "*": "attn_blocks"}
+
+
+def _kinds(cfg) -> list[tuple[str, int]]:
+    """(mixer kind, index among the layers of its kind) of each layer."""
+    seen = dict.fromkeys(_KIND_BLOCKS, 0)
+    out = []
+    for kind in cfg.layer_kinds:
+        out.append((kind, seen[kind]))
+        seen[kind] += 1
+    return out
+
+
+def _layer(params, kind: str, i: int):
+    """Layer ``i`` of ``kind``'s stacked parameters; an MoE layer keeps
+    the experts' weights stacked, for the kernel to read in place."""
+    lp = jax.tree.map(lambda a: a[i], params[_KIND_BLOCKS[kind]])
+    if kind == "E":
+        stack = params["moe_blocks"]["moe"]
+        lp["moe"] = dict(lp["moe"], w_up=stack["w_up"], w_down=stack["w_down"])
+    return lp
+
+
+def _moe_fold(stats: list, prev=None):
+    """Per-layer MoE stats (rows, rows_max, experts) summed, maxed,
+    summed; onto ``prev`` where given."""
+    acc = jnp.zeros((3,), jnp.int32) if prev is None else prev
+    for st in stats:
+        acc = jnp.stack([acc[0] + st[0], jnp.maximum(acc[1], st[1]),
+                         acc[2] + st[2]])
+    return acc
+
+
+def _nemotron_h_layers(cfg, params, h, cache, shd, pos, *, decode: bool):
+    """``nemotron_h``'s layers over ``h`` and their cache: a prompt's
+    prefill into an empty cache, or with ``decode`` one step.  Returns
+    (h, the new cache), the MoE layers' stats folded onto the phase's row
+    of ``moe_stats``."""
+    idx, phase = cache["len"], int(decode)
+    ssm, conv, ks, vs, stats = [], [], [], [], []
+    for kind, i in _kinds(cfg):
+        lp = _layer(params, kind, i)
+        x = L.rms_norm(h, lp["ln1"], cfg.norm_eps)
+        if kind == "M":
+            state = ({"ssm": cache["ssm"][i], "conv": cache["conv"][i]}
+                     if decode else None)
+            m, ns = L.mamba2_block(lp["mamba"], x, cfg, shd, state=state,
+                                   use_kernel=cfg.use_kernels)
+            ssm.append(ns["ssm"])
+            conv.append(ns["conv"])
+        elif kind == "E":
+            m, st = L.held_moe_block(lp["moe"], x, cfg, shd,
+                                     use_kernel=cfg.use_kernels, layer=i)
+            stats.append(st)
+        else:
+            ck, cv = cache["attn"]["k"][i], cache["attn"]["v"][i]
+            if decode:
+                m, kv = L.gqa_attention(
+                    lp["attn"], x, cfg, shd, positions=pos,
+                    cache={"k": ck, "v": cv, "len": idx})
+            else:
+                m, kv = L.gqa_attention(
+                    lp["attn"], x, cfg, shd, positions=pos,
+                    use_flash="pallas" if cfg.use_kernels else None)
+                kv = {n: jax.lax.dynamic_update_slice_in_dim(c, kv[n], 0, 1)
+                      for n, c in (("k", ck), ("v", cv))}
+            ks.append(kv["k"])
+            vs.append(kv["v"])
+        h = h + m
+    moe = cache["moe_stats"]
+    return h, {"ssm": jnp.stack(ssm), "conv": jnp.stack(conv),
+               "attn": {"k": jnp.stack(ks), "v": jnp.stack(vs)},
+               "len": idx + h.shape[1],
+               "moe_stats": moe.at[phase].set(_moe_fold(stats, moe[phase]))}
 
 
 # ---------------------------------------------------------------------------
@@ -133,6 +217,16 @@ def init_params(cfg, key) -> dict:
         params["blocks"] = _stack_init(mamba_block, k_blocks, cfg.n_layers)
         params["shared_attn"] = {"ln": jnp.ones((cfg.d_model,), dt),
                                  "attn": L.gqa_init(k_extra, cfg, dt)}
+    elif bp == "nemotron_h":
+        inits = {
+            "M": mamba_block,
+            "E": lambda k: {"ln1": jnp.ones((cfg.d_model,), dt),
+                            "moe": L.held_moe_init(k, cfg, dt)},
+            "*": lambda k: {"ln1": jnp.ones((cfg.d_model,), dt),
+                            "attn": L.gqa_init(k, cfg, dt)}}
+        for kind, k in zip(_KIND_BLOCKS, jax.random.split(k_blocks, 3)):
+            params[_KIND_BLOCKS[kind]] = _stack_init(
+                inits[kind], k, cfg.layer_kinds.count(kind))
     else:
         raise ValueError(f"unknown block pattern {bp!r}")
     return params
@@ -287,6 +381,20 @@ def forward(cfg, params, batch, shd: Policy = NO_POLICY,
                                    cfg, shd, positions=pos)
             return h + a, None
         h, _ = jax.lax.scan(_maybe_remat(group_body, cfg), h, grouped)
+
+    elif bp == "nemotron_h":
+        def layer(kind, i, lp, h):
+            x = L.rms_norm(h, lp["ln1"], cfg.norm_eps)
+            if kind == "M":
+                m, _ = L.mamba2_block(lp["mamba"], x, cfg, shd)
+            elif kind == "E":
+                m, _ = L.held_moe_block(lp["moe"], x, cfg, shd, layer=i)
+            else:
+                m, _ = L.gqa_attention(lp["attn"], x, cfg, shd, positions=pos)
+            return h + m
+        for kind, i in _kinds(cfg):
+            h = _maybe_remat(partial(layer, kind, i), cfg)(
+                _layer(params, kind, i), h)
     else:
         raise ValueError(bp)
 
@@ -398,6 +506,20 @@ def init_cache(cfg, batch: int, max_len: int) -> dict:
             "conv": jnp.zeros((Lc, batch, cfg.ssm_conv - 1, conv_dim), dt),
             "attn": attn_cache(G, max_len),
             "len": jnp.zeros((), jnp.int32)}
+    if bp == "nemotron_h":
+        # SSM and convolution state of the Mamba2 layers beside the K/V
+        # of the attention layers; moe_stats: the MoE layers' (rows,
+        # rows_max, experts) of the prefill [0] and of the decode steps [1]
+        kinds = cfg.layer_kinds
+        n_m = kinds.count("M")
+        conv_dim = cfg.ssm_d_inner + 2 * cfg.ssm_state * cfg.ssm_groups
+        return {
+            "ssm": jnp.zeros((n_m, batch, cfg.ssm_heads, cfg.ssm_state,
+                              cfg.ssm_headdim), jnp.float32),
+            "conv": jnp.zeros((n_m, batch, cfg.ssm_conv - 1, conv_dim), dt),
+            "attn": attn_cache(kinds.count("*"), max_len),
+            "len": jnp.zeros((), jnp.int32),
+            "moe_stats": jnp.zeros((2, 3), jnp.int32)}
     raise ValueError(bp)
 
 
@@ -529,6 +651,10 @@ def decode_step(cfg, params, cache, batch, shd: Policy = NO_POLICY):
             "ssm": nssm.reshape(cfg.n_layers, *nssm.shape[2:]),
             "conv": nconv.reshape(cfg.n_layers, *nconv.shape[2:]),
             "attn": {"k": nk, "v": nv}, "len": idx + T}
+
+    elif bp == "nemotron_h":
+        h, new_cache = _nemotron_h_layers(cfg, params, h, cache, shd, pos,
+                                          decode=True)
     else:
         raise ValueError(bp)
 
@@ -689,7 +815,6 @@ def prefill(cfg, params, batch, max_len: int, shd: Policy = NO_POLICY):
         grouped = jax.tree.map(
             lambda x: x.reshape(G, every, *x.shape[1:]), params["blocks"])
         sa = params["shared_attn"]
-        conv_dim = cfg.ssm_d_inner + 2 * cfg.ssm_state * cfg.ssm_groups
 
         def group_body(h, xs):
             glp, ck, cv = xs
@@ -697,11 +822,7 @@ def prefill(cfg, params, batch, max_len: int, shd: Policy = NO_POLICY):
                 x = L.rms_norm(h, lp["ln1"], cfg.norm_eps)
                 m, ns = L.mamba2_block(lp["mamba"], x, cfg, shd,
                                        use_kernel=cfg.use_kernels)
-                # conv tail state for decode continuation
-                zxbcdt = x @ lp["mamba"]["in_proj"]
-                xbc = zxbcdt[..., cfg.ssm_d_inner:cfg.ssm_d_inner + conv_dim]
-                conv_tail = xbc[:, -(cfg.ssm_conv - 1):, :]
-                return h + m, (ns["ssm"], conv_tail)
+                return h + m, (ns["ssm"], ns["conv"])
             h, (ssm_g, conv_g) = jax.lax.scan(inner, h, glp)
             x = L.rms_norm(h, sa["ln"], cfg.norm_eps)
             k = (x @ sa["attn"]["wk"]).reshape(B, T, cfg.n_kv_heads, cfg.d_head)
@@ -721,6 +842,10 @@ def prefill(cfg, params, batch, max_len: int, shd: Policy = NO_POLICY):
             "ssm": nssm.reshape(cfg.n_layers, *nssm.shape[2:]),
             "conv": nconv.reshape(cfg.n_layers, *nconv.shape[2:]),
             "attn": {"k": nk, "v": nv}, "len": jnp.asarray(T, jnp.int32)}
+
+    elif bp == "nemotron_h":
+        h, cache = _nemotron_h_layers(cfg, params, h, cache, shd, pos,
+                                      decode=False)
     else:
         raise ValueError(bp)
 

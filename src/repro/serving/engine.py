@@ -12,6 +12,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .. import telemetry
@@ -178,7 +179,9 @@ class Engine:
         ``engine.prefill`` around the prompt's forward pass (with
         ``model.prefill.*`` under it only on a call that traces), and
         ``engine.decode`` around the token loop, one ``engine.decode_step``
-        per step's dispatch and pick."""
+        per step's dispatch and pick.  A model with MoE layers carries
+        their stats in the cache's ``moe_stats``; they are read once, at
+        the end, into ``repro.telemetry``'s ``moe.*`` counters."""
         B, T = prompt_tokens.shape
         max_len = max_len or (T + max_new)
         self._calls += 1
@@ -197,7 +200,19 @@ class Engine:
                         logits, cache = step(self.params, cache,
                                              {"tokens": tok})
                         tok = _greedy(logits)
+                if "moe_stats" in cache:
+                    _count_moe(cache["moe_stats"])
                 return jnp.concatenate(outs, axis=1)
+
+
+def _count_moe(stats) -> None:
+    """The MoE stats of one generate, (prefill, decode) x (rows,
+    rows_max, experts), into the telemetry counters."""
+    stats = np.asarray(stats)
+    for phase, (rows, rows_max, experts) in zip(("prefill", "decode"), stats):
+        telemetry.add(f"moe.rows/{phase}", rows)
+        telemetry.add(f"moe.experts/{phase}", experts)
+        telemetry.peak(f"moe.rows_max/{phase}", rows_max)
 
 
 def _greedy(logits):

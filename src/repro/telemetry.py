@@ -21,6 +21,16 @@ when it is full the oldest is dropped and ``counters()["dropped"]``
 counts it.  A compile event with no span open in its thread counts
 under ``counters()["compiles/none"]``.
 
+``add(key, n)`` adds to a counter of the program's own and ``peak(key,
+n)`` raises a high-water mark; both are counted whether or not a trace
+is active, and ``counters()`` returns them beside the recorder's.  The
+served model's MoE layers report through them once per
+``Engine.generate``: ``moe.rows/<phase>``, the (token, expert) pairs that
+landed on held experts, ``moe.experts/<phase>``, the held experts with
+any, summed over layer calls, and ``moe.rows_max/<phase>``, the most
+pairs on one held expert in one layer call; ``<phase>`` is ``prefill``
+or ``decode``.
+
 Spans inside a function that ``jax.jit`` traces fire once, at trace
 time, and time the tracing; under ``jit`` a ``model.prefill.*`` span
 therefore times how long its part of the program took to trace.
@@ -115,6 +125,14 @@ class Recorder:
         with self._lock:
             self._counts[key] += 1
 
+    def add(self, key: str, n: int = 1) -> None:
+        with self._lock:
+            self._counts[key] = self._counts.get(key, 0) + int(n)
+
+    def peak(self, key: str, n: int) -> None:
+        with self._lock:
+            self._counts[key] = max(self._counts.get(key, 0), int(n))
+
     def spans(self) -> list[Span]:
         """The kept spans, oldest first."""
         with self._lock:
@@ -129,6 +147,8 @@ RECORDER = Recorder()
 span = RECORDER.span
 spans = RECORDER.spans
 counters = RECORDER.counters
+add = RECORDER.add
+peak = RECORDER.peak
 
 
 def _on_event(name: str, *_args, **_kw) -> None:

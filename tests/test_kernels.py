@@ -253,3 +253,41 @@ def test_moe_dispatch_capacity_invariants():
             if keep_n[t, k]:
                 kept_per_e[gi_n[t, k]] += 1
     assert (kept_per_e <= cap).all()
+
+
+# ---------------------------------------------------------------------------
+# relu^2 experts over sorted, grouped rows (expert_mlp)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("sizes,rows,d,F,bm,bf", [
+    ((5, 0, 17, 3), 30, 64, 48, 16, 16),     # uneven, one empty, rows past
+    ((0, 40, 0), 40, 32, 64, 16, 32),        # one group holds every row
+    ((1, 1, 1, 1, 1, 1), 8, 128, 32, 16, 32),  # decode-like: a row each
+])
+def test_expert_mlp_matches_ragged_dot(sizes, rows, d, F, bm, bf, dtype):
+    """The kernel over ``block_layout``'s padded blocks against the layer's
+    ``jax.numpy`` path (``ragged_dot`` over the sorted rows); f32 at
+    HIGHEST, bf16 at the MXU's default with an f32 accumulator."""
+    G = len(sizes)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    ks = jax.random.split(jax.random.PRNGKey(13), 3)
+    x = rand(ks[0], (rows, d), dtype)
+    w_up = (rand(ks[1], (G, F, d), jnp.float32) * d ** -0.5).astype(dtype)
+    w_down = (rand(ks[2], (G, F, d), jnp.float32) * F ** -0.5).astype(dtype)
+    place, block_group, n_blocks, padded = ops.block_layout(sizes, rows, bm)
+    xp = jnp.zeros((padded + 1, d), dtype).at[place].set(x)[:padded]
+    y = ops.expert_mlp(xp, w_up, w_down, block_group, n_blocks, block_m=bm,
+                       block_f=bf, interpret=True)
+    assert y.shape == (padded, d) and y.dtype == x.dtype
+    got = jnp.concatenate([y, jnp.zeros((1, d), dtype)])[place]
+    with jax.default_matmul_precision("highest"):
+        h = jax.lax.ragged_dot(x, jnp.swapaxes(w_up, 1, 2), sizes)
+        want = jax.lax.ragged_dot(jnp.square(jax.nn.relu(h)).astype(dtype),
+                                  w_down, sizes)
+    tol = 5e-2 if dtype == jnp.bfloat16 else 2e-4
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=tol, rtol=tol)
+    # rows past the groups are placed nowhere
+    assert (np.asarray(place)[int(sizes.sum()):] == padded).all()
+    assert int(n_blocks[0]) == int(sum(-(-int(s) // bm) for s in sizes))

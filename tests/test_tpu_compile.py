@@ -48,15 +48,22 @@ def _ssd(c, b, v, log_a):
     return ops.ssd_scan(c, b, v, log_a, chunk=256, interpret=False)
 
 
+def _expert_mlp(bm):
+    def run(x, w_up, w_down, block_group, n_blocks):
+        return ops.expert_mlp(x, w_up, w_down, block_group, n_blocks,
+                              block_m=bm, interpret=False)
+    return run
+
+
 def _expert_glu(x, w_up, w_down):
     return ops.expert_glu(x, w_up, w_down, block_m=128, block_f=256,
                           interpret=False)
 
 
 # (kernel, argument shapes and dtypes): zamba2-2.7b's shared attention and
-# Mamba2 layer, the expert GLU at bf16, and the three kernels at the f32
-# widths of the smoke's kernel chains (32 heads x 64, state 64, d 2048,
-# cap 512; and the four-chip path's)
+# Mamba2 layer, the expert GLU at bf16, nemotron-3-nano's experts, and the
+# three kernels at the f32 widths of the smoke's kernel chains (32 heads x
+# 64, state 64, d 2048, cap 512; and the four-chip path's)
 CASES = {
     "attention-zamba2-bf16": (_attention, [((1, 2048, 32, 80), BF16)] * 3),
     "attention-chain-f32": (_attention, [((1, 2048, 32, 64), F32)] * 3),
@@ -77,6 +84,14 @@ CASES = {
     "expert_glu-chain4-f32": (_expert_glu, [((8, 128, 64), F32),
                                             ((8, 64, 512), F32),
                                             ((8, 256, 64), F32)]),
+    # nemotron-3-nano's 16 held experts of 1856 at hidden 2688: a decode
+    # step's 32 tokens x top 6 and a prefill's 32 x 256 tokens
+    "expert_mlp-nemotron-decode": (_expert_mlp(16), [
+        ((432, 2688), BF16), ((16, 1856, 2688), BF16),
+        ((16, 1856, 2688), BF16), ((27,), jnp.int32), ((1,), jnp.int32)]),
+    "expert_mlp-nemotron-prefill": (_expert_mlp(128), [
+        ((51200, 2688), BF16), ((16, 1856, 2688), BF16),
+        ((16, 1856, 2688), BF16), ((400,), jnp.int32), ((1,), jnp.int32)]),
 }
 
 
