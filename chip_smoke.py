@@ -388,7 +388,9 @@ def phase_model(dev, arch=MODEL["arch"], n_layers=MODEL["n_layers"],
     tok0 = jnp.argmax(lp[:, -1], axis=-1)[:, None].astype(jnp.int32)
     require((np.asarray(tok0) == gen_np[:, :1]).all(), "model",
             "first generated token is not the prefill argmax")
-    ld, _ = eng.decode_step_fn()(params, cache, {"tokens": tok0})
+    # the step donates its cache, and the prefill's is compared below
+    ld, _ = eng.decode_step_fn()(params, jax.tree.map(jnp.copy, cache),
+                                 {"tokens": tok0})
 
     cfg32 = dataclasses.replace(cfg, dtype="float32", use_kernels=False)
     p32 = jax.tree.map(lambda a: a.astype(jnp.float32), params)

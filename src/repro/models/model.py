@@ -17,7 +17,7 @@ Every assigned architecture maps to one of five block patterns:
                  without positions, as ``cfg.layer_pattern`` says
                  (nemotron-3-nano)
 
-All patterns but ``nemotron_h`` scan over stacked layer parameters so HLO
+All patterns but ``nemotron_h`` loop over stacked layer parameters so HLO
 size (and CPU compile time for the 512-device dry-run) is
 depth-independent.  ``nemotron_h`` stacks each mixer kind's parameters
 and unrolls its layers: its pattern is not periodic, and its runs of one
@@ -82,23 +82,26 @@ def _nemotron_h_layers(cfg, params, h, cache, shd, pos, *, decode: bool):
     (h, the new cache), the MoE layers' stats folded onto the phase's row
     of ``moe_stats``."""
     idx, phase = cache["len"], int(decode)
-    ssm, conv, ks, vs, stats = [], [], [], [], []
+    # each layer's state is written into the cache's own stacks, so a
+    # donated cache is updated in place and not copied
+    ssm, conv = cache["ssm"], cache["conv"]
+    ks, vs = cache["attn"]["k"], cache["attn"]["v"]
+    stats = []
     for kind, i in _kinds(cfg):
         lp = _layer(params, kind, i)
         x = L.rms_norm(h, lp["ln1"], cfg.norm_eps)
         if kind == "M":
-            state = ({"ssm": cache["ssm"][i], "conv": cache["conv"][i]}
-                     if decode else None)
+            state = {"ssm": ssm[i], "conv": conv[i]} if decode else None
             m, ns = L.mamba2_block(lp["mamba"], x, cfg, shd, state=state,
                                    use_kernel=cfg.use_kernels)
-            ssm.append(ns["ssm"])
-            conv.append(ns["conv"])
+            ssm = ssm.at[i].set(ns["ssm"])
+            conv = conv.at[i].set(ns["conv"])
         elif kind == "E":
             m, st = L.held_moe_block(lp["moe"], x, cfg, shd,
                                      use_kernel=cfg.use_kernels, layer=i)
             stats.append(st)
         else:
-            ck, cv = cache["attn"]["k"][i], cache["attn"]["v"][i]
+            ck, cv = ks[i], vs[i]
             if decode:
                 m, kv = L.gqa_attention(
                     lp["attn"], x, cfg, shd, positions=pos,
@@ -109,12 +112,11 @@ def _nemotron_h_layers(cfg, params, h, cache, shd, pos, *, decode: bool):
                     use_flash="pallas" if cfg.use_kernels else None)
                 kv = {n: jax.lax.dynamic_update_slice_in_dim(c, kv[n], 0, 1)
                       for n, c in (("k", ck), ("v", cv))}
-            ks.append(kv["k"])
-            vs.append(kv["v"])
+            ks = ks.at[i].set(kv["k"])
+            vs = vs.at[i].set(kv["v"])
         h = h + m
     moe = cache["moe_stats"]
-    return h, {"ssm": jnp.stack(ssm), "conv": jnp.stack(conv),
-               "attn": {"k": jnp.stack(ks), "v": jnp.stack(vs)},
+    return h, {"ssm": ssm, "conv": conv, "attn": {"k": ks, "v": vs},
                "len": idx + h.shape[1],
                "moe_stats": moe.at[phase].set(_moe_fold(stats, moe[phase]))}
 
@@ -527,6 +529,74 @@ def init_cache(cfg, batch: int, max_len: int) -> dict:
 # decode step (one token; the ``serve_step`` the dry-run lowers)
 # ---------------------------------------------------------------------------
 
+_at = partial(jax.lax.dynamic_index_in_dim, keepdims=False)
+_put = partial(jax.lax.dynamic_update_index_in_dim, axis=0)
+
+
+def _scan_layers(body, h, xs, cache):
+    """``body(h, x, entry) -> (h, new entry)`` over stacked layers: ``xs``
+    (the layers' weights) are scanned, and ``cache`` (stacks of the
+    layers' entries) is carried whole, each layer's new entry written
+    back into its stack by index.  A donated cache is so updated where
+    it lies: new stacks built as the scan's outputs would be copied into
+    the donated buffers after the loop."""
+    def step(carry, x_i):
+        h, c = carry
+        x, i = x_i
+        h, new = body(h, x, jax.tree.map(lambda a: _at(a, i), c))
+        return (h, jax.tree.map(lambda a, e: _put(a, e, i), c, new)), None
+
+    n = jax.tree.leaves(xs)[0].shape[0]
+    (h, cache), _ = jax.lax.scan(step, (h, cache), (xs, jnp.arange(n)))
+    return h, cache
+
+
+def _zamba2_decode_layers(cfg, params, h, cache, shd, pos):
+    """zamba2's layers for one token: each Mamba2 layer reads its weights
+    and state from the full stacks by global layer index, and the shared
+    attention runs after every ``zamba_attn_every``-th layer on its
+    group's K/V.  Nothing is sliced as a sub-stack: a scan over groups
+    whose ``xs`` are stacks of layers makes XLA copy each group's weights
+    before the layers read them."""
+    every = cfg.zamba_attn_every
+    blocks = params["blocks"]
+    sa = params["shared_attn"]
+    # ``in_proj`` read through a transposed view, whose per-layer ``.T``
+    # below folds into the dot: zamba2-2.7b's is 10448 wide, off the
+    # 128-lane tiling, so a TPU keeps its rows minor, and a row-major read
+    # copies the whole stack each step
+    blocks = {**blocks, "mamba": {
+        **blocks["mamba"],
+        "in_proj": jnp.swapaxes(blocks["mamba"]["in_proj"], -1, -2)}}
+
+    def group(g, carry):
+        def layer(j, carry):
+            h, ssm, conv = carry
+            i = g * every + j
+            lp = jax.tree.map(lambda a: _at(a, i), blocks)
+            lp["mamba"]["in_proj"] = lp["mamba"]["in_proj"].T
+            state = {"ssm": _at(ssm, i), "conv": _at(conv, i)}
+            m, ns = L.mamba2_block(lp["mamba"],
+                                   L.rms_norm(h, lp["ln1"], cfg.norm_eps),
+                                   cfg, shd, state=state)
+            return h + m, _put(ssm, ns["ssm"], i), _put(conv, ns["conv"], i)
+
+        h, ssm, conv, ck, cv = carry
+        h, ssm, conv = jax.lax.fori_loop(0, every, layer, (h, ssm, conv))
+        a, nc = L.gqa_attention(sa["attn"],
+                                L.rms_norm(h, sa["ln"], cfg.norm_eps), cfg,
+                                shd, positions=pos,
+                                cache={"k": _at(ck, g), "v": _at(cv, g),
+                                       "len": cache["len"]})
+        return h + a, ssm, conv, _put(ck, nc["k"], g), _put(cv, nc["v"], g)
+
+    h, ssm, conv, nk, nv = jax.lax.fori_loop(
+        0, cfg.n_layers // every, group,
+        (h, cache["ssm"], cache["conv"], cache["attn"]["k"],
+         cache["attn"]["v"]))
+    return h, {"ssm": ssm, "conv": conv, "attn": {"k": nk, "v": nv}}
+
+
 def decode_step(cfg, params, cache, batch, shd: Policy = NO_POLICY):
     """One decode step.  batch: tokens (B, 1) (+ embeds for stubs).
     Returns (logits (B, 1, V), new_cache)."""
@@ -541,11 +611,10 @@ def decode_step(cfg, params, cache, batch, shd: Policy = NO_POLICY):
     bp = cfg.block_pattern
 
     if bp in ("dense", "moe"):
-        def body(h, xs):
-            lp, ck, cv = xs
+        def body(h, lp, kv):
             a, nc = L.gqa_attention(
                 lp["attn"], L.rms_norm(h, lp["ln1"], cfg.norm_eps), cfg, shd,
-                positions=pos, cache={"k": ck, "v": cv, "len": idx})
+                positions=pos, cache={**kv, "len": idx})
             h = h + a
             if bp == "moe":
                 m, _ = L.moe_block(lp["moe"], L.rms_norm(h, lp["ln2"], cfg.norm_eps),
@@ -553,18 +622,16 @@ def decode_step(cfg, params, cache, batch, shd: Policy = NO_POLICY):
             else:
                 m = L.swiglu_mlp(lp["mlp"], L.rms_norm(h, lp["ln2"], cfg.norm_eps),
                                  shd)
-            return h + m, (nc["k"], nc["v"])
-        h, (nk, nv) = jax.lax.scan(
-            body, h, (params["blocks"], cache["attn"]["k"], cache["attn"]["v"]))
-        new_cache = {"attn": {"k": nk, "v": nv}, "len": idx + T}
+            return h + m, {"k": nc["k"], "v": nc["v"]}
+        h, kv = _scan_layers(body, h, params["blocks"], cache["attn"])
+        new_cache = {"attn": kv, "len": idx + T}
 
     elif bp == "mla_moe":
         def mk_body(is_moe):
-            def body(h, xs):
-                lp, cc, cp = xs
+            def body(h, lp, c):
                 a, nc = L.mla_attention(
                     lp["attn"], L.rms_norm(h, lp["ln1"], cfg.norm_eps), cfg, shd,
-                    positions=pos, cache={"c_kv": cc, "k_pe": cp, "len": idx})
+                    positions=pos, cache={**c, "len": idx})
                 h = h + a
                 if is_moe:
                     m, _ = L.moe_block(lp["moe"],
@@ -573,23 +640,20 @@ def decode_step(cfg, params, cache, batch, shd: Policy = NO_POLICY):
                 else:
                     m = L.swiglu_mlp(lp["mlp"],
                                      L.rms_norm(h, lp["ln2"], cfg.norm_eps), shd)
-                return h + m, (nc["c_kv"], nc["k_pe"])
+                return h + m, {"c_kv": nc["c_kv"], "k_pe": nc["k_pe"]}
             return body
-        h, (dc, dp) = jax.lax.scan(
-            mk_body(False), h,
-            (params["dense_blocks"], cache["dense"]["c_kv"], cache["dense"]["k_pe"]))
-        h, (mc, mp) = jax.lax.scan(
-            mk_body(True), h,
-            (params["moe_blocks"], cache["moe"]["c_kv"], cache["moe"]["k_pe"]))
-        new_cache = {"dense": {"c_kv": dc, "k_pe": dp},
-                     "moe": {"c_kv": mc, "k_pe": mp}, "len": idx + T}
+        h, dense = _scan_layers(mk_body(False), h, params["dense_blocks"],
+                                cache["dense"])
+        h, moe = _scan_layers(mk_body(True), h, params["moe_blocks"],
+                              cache["moe"])
+        new_cache = {"dense": dense, "moe": moe, "len": idx + T}
 
     elif bp == "encdec":
-        def body(h, xs):
-            lp, ck, cv, xk, xv = xs
+        def body(h, xs, kv):
+            lp, xk, xv = xs
             a, nc = L.gqa_attention(
                 lp["attn"], L.rms_norm(h, lp["ln1"], cfg.norm_eps), cfg, shd,
-                positions=pos, cache={"k": ck, "v": cv, "len": idx})
+                positions=pos, cache={**kv, "len": idx})
             h = h + a
             # cross-attention against cached encoder K/V
             xq = (L.rms_norm(h, lp["lnx"], cfg.norm_eps) @ lp["xattn"]["wq"])
@@ -598,59 +662,29 @@ def decode_step(cfg, params, cache, batch, shd: Policy = NO_POLICY):
             xo = L._decode_attention(xq, xk, xv, valid, q_offset=xk.shape[1])
             h = h + xo.reshape(B, T, -1) @ lp["xattn"]["wo"]
             m = L.swiglu_mlp(lp["mlp"], L.rms_norm(h, lp["ln2"], cfg.norm_eps), shd)
-            return h + m, (nc["k"], nc["v"])
-        h, (nk, nv) = jax.lax.scan(
-            body, h, (params["dec_blocks"], cache["attn"]["k"],
-                      cache["attn"]["v"], cache["xk"], cache["xv"]))
-        new_cache = {"attn": {"k": nk, "v": nv}, "xk": cache["xk"],
-                     "xv": cache["xv"], "len": idx + T}
+            return h + m, {"k": nc["k"], "v": nc["v"]}
+        h, kv = _scan_layers(body, h, (params["dec_blocks"], cache["xk"],
+                                       cache["xv"]), cache["attn"])
+        new_cache = {"attn": kv, "xk": cache["xk"], "xv": cache["xv"],
+                     "len": idx + T}
 
     elif bp == "xlstm":
-        def body(h, xs):
-            lp, ms, ss = xs
+        def body(h, lp, st):
             a, nm = L.mlstm_block(lp["mlstm"],
                                   L.rms_norm(h, lp["ln_m"], cfg.norm_eps),
-                                  cfg, shd, state={"ssm": ms})
+                                  cfg, shd, state={"ssm": st["mlstm"]})
             h = h + a
             s, ns = L.slstm_block(lp["slstm"],
                                   L.rms_norm(h, lp["ln_s"], cfg.norm_eps),
-                                  cfg, shd, state={"slstm": ss})
-            return h + s, (nm["ssm"], ns["slstm"])
-        h, (nms, nss) = jax.lax.scan(body, h,
-                                     (params["blocks"], cache["mlstm"],
-                                      cache["slstm"]))
-        new_cache = {"mlstm": nms, "slstm": nss, "len": idx + T}
+                                  cfg, shd, state={"slstm": st["slstm"]})
+            return h + s, {"mlstm": nm["ssm"], "slstm": ns["slstm"]}
+        h, st = _scan_layers(body, h, params["blocks"],
+                             {"mlstm": cache["mlstm"], "slstm": cache["slstm"]})
+        new_cache = {**st, "len": idx + T}
 
     elif bp == "zamba2":
-        every = cfg.zamba_attn_every
-        G = cfg.n_layers // every
-        grouped = jax.tree.map(
-            lambda x: x.reshape(G, every, *x.shape[1:]), params["blocks"])
-        gssm = cache["ssm"].reshape(G, every, *cache["ssm"].shape[1:])
-        gconv = cache["conv"].reshape(G, every, *cache["conv"].shape[1:])
-        sa = params["shared_attn"]
-
-        def group_body(h, xs):
-            glp, ssm_g, conv_g, ck, cv = xs
-            def inner(h, ixs):
-                lp, s, c = ixs
-                m, ns = L.mamba2_block(lp["mamba"],
-                                       L.rms_norm(h, lp["ln1"], cfg.norm_eps),
-                                       cfg, shd, state={"ssm": s, "conv": c})
-                return h + m, (ns["ssm"], ns["conv"])
-            h, (nssm, nconv) = jax.lax.scan(inner, h, (glp, ssm_g, conv_g))
-            a, nc = L.gqa_attention(sa["attn"],
-                                    L.rms_norm(h, sa["ln"], cfg.norm_eps), cfg,
-                                    shd, positions=pos,
-                                    cache={"k": ck, "v": cv, "len": idx})
-            return h + a, (nssm, nconv, nc["k"], nc["v"])
-        h, (nssm, nconv, nk, nv) = jax.lax.scan(
-            group_body, h, (grouped, gssm, gconv,
-                            cache["attn"]["k"], cache["attn"]["v"]))
-        new_cache = {
-            "ssm": nssm.reshape(cfg.n_layers, *nssm.shape[2:]),
-            "conv": nconv.reshape(cfg.n_layers, *nconv.shape[2:]),
-            "attn": {"k": nk, "v": nv}, "len": idx + T}
+        h, new_cache = _zamba2_decode_layers(cfg, params, h, cache, shd, pos)
+        new_cache["len"] = idx + T
 
     elif bp == "nemotron_h":
         h, new_cache = _nemotron_h_layers(cfg, params, h, cache, shd, pos,

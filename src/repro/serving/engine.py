@@ -137,7 +137,9 @@ class Engine:
         ``jax.jit``'s own cache keys on argument shapes/dtypes, so one
         jitted callable per engine covers every (batch, cache-length)
         combination — new shapes trace once, repeats hit the compile
-        cache.
+        cache.  The cache argument is donated, as in ``jit_decode_step``:
+        the step writes the new cache into its buffers, and the caller's
+        cache is deleted.
         """
         if self._jit_decode is None:
             def step(params, cache, batch):
@@ -148,7 +150,7 @@ class Engine:
                     self.decode_trace_counts.get(key, 0) + 1
                 return M.decode_step(self.cfg, params, cache, batch,
                                      self.policy)
-            self._jit_decode = jax.jit(step)
+            self._jit_decode = jax.jit(step, donate_argnums=1)
         return self._jit_decode
 
     def prefill_fn(self):

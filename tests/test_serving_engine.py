@@ -103,3 +103,33 @@ def test_jitted_prefill_tokens_match_eager_prefill(name, overrides):
         tok = _greedy(logits)
     np.testing.assert_array_equal(np.asarray(served),
                                   np.asarray(jnp.concatenate(outs, axis=1)))
+
+
+@pytest.mark.parametrize("name", [
+    "llama3.2-1b", "zamba2-2.7b", "nemotron-3-nano-30b-a3b",
+    "granite-moe-1b-a400m", "deepseek-v3-671b", "xlstm-125m",
+], ids=["dense", "zamba2", "nemotron_h", "moe", "mla_moe", "xlstm"])
+def test_decode_step_donates_its_cache(name):
+    """The engine's step writes the new cache into the old one's buffers,
+    so the cache it is given is deleted; ``generate`` still serves the
+    tokens of a loop whose step donates nothing."""
+    eng = _fresh(name)
+    toks = _prompts(eng, seq=8)
+    new = 4
+    logits, cache = M.prefill(eng.cfg, eng.params, {"tokens": toks},
+                              max_len=8 + new, shd=eng.policy)
+    kept = jax.tree.map(jnp.copy, cache)
+    eng.decode_step_fn()(eng.params, cache, {"tokens": _greedy(logits)})
+    assert all(a.is_deleted() for a in jax.tree.leaves(cache))
+
+    served = eng.generate(toks, max_new=new)
+    step = jax.jit(lambda p, c, b: M.decode_step(eng.cfg, p, c, b,
+                                                 eng.policy))
+    tok, outs, cache = _greedy(logits), [], kept
+    for _ in range(new):
+        outs.append(tok)
+        logits, cache = step(eng.params, cache, {"tokens": tok})
+        tok = _greedy(logits)
+    assert not any(a.is_deleted() for a in jax.tree.leaves(cache))
+    np.testing.assert_array_equal(np.asarray(served),
+                                  np.asarray(jnp.concatenate(outs, axis=1)))
